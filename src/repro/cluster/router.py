@@ -467,7 +467,8 @@ class ClusterKV:
             pids.append(pid)
             imgs.append(np.ascontiguousarray(img, dtype=np.uint8))
         ps = self.cfg.kv.page_size
-        if pids and self.cfg.kernel_impl != "staged" and ps % 128 == 0:
+        # the kernel checks pages as whole rows of 128 int32 words
+        if pids and self.cfg.kernel_impl != "staged" and ps % 512 == 0:
             from repro.kernels.apply_unpack import apply_unpack
             packed = np.concatenate([i.reshape(-1) for i in imgs])
             expected = np.array(

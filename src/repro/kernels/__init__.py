@@ -12,9 +12,12 @@ The paper optimizes I/O; the on-device work of its adapted primitives is:
                        (flush_pack's inverse)
 
 Each subpackage has kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-dispatch wrapper: Pallas on TPU, ref elsewhere), ref.py (pure-jnp oracle).
-Kernels are validated in interpret mode against the oracles with
-hypothesis-driven shape/dtype sweeps (tests/test_kernels.py).
+dispatch wrapper, per ``common.resolve_impl``: compiled Pallas on TPU, the
+oracle elsewhere unless interpret mode is asked for), ref.py (pure-jnp
+oracle). Kernels are validated in interpret mode against the oracles with
+hypothesis-driven shape/dtype sweeps (tests/test_kernels.py), and compiled
+for a described v5e at the Trainer's real shapes
+(tests/test_tpu_compile.py).
 """
 
 from repro.kernels.apply_unpack import ApplyUnpack, apply_unpack  # noqa: F401

@@ -12,9 +12,11 @@ read bandwidth is the scarce, thread-scalable resource).
 
 Grid: one program per packed block, destination driven by a
 scalar-prefetched index vector (the canonical Pallas TPU scatter, same
-shape as ``delta_pack``'s apply kernel). The base image is aliased into
-the output, so unreferenced blocks are never copied; the per-block
-popcounts and checksum verdicts stream out as small column outputs.
+shape as ``delta_pack``'s apply kernel). The base image stays in HBM
+(``pl.ANY``) aliased into the output, so unreferenced blocks are never
+read or copied. Each block's popcount streams out as a lane-broadcast
+(1, 1, 128) row — the block shape the TPU tiling accepts for one value
+per step; the checksum verdicts are compared outside the kernel.
 """
 
 from __future__ import annotations
@@ -26,21 +28,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import LANES
-
-_UINT_FOR = {4: jnp.uint32, 2: jnp.uint16, 1: jnp.uint8}
+from repro.kernels.common import LANES, as_words, block_reduce
 
 
-def _apply_unpack_kernel(idx_ref, upd_ref, exp_ref, base_ref,
-                         out_ref, ok_ref, cnt_ref):
+def _apply_unpack_kernel(idx_ref, upd_ref, base_ref, out_ref, cnt_ref):
     # base_ref is aliased into out_ref and never read: the kernel's only
-    # job at this grid step is to land the packed block and its verdict.
+    # job at this grid step is to land the packed block and its count.
     upd = upd_ref[...]
-    udt = _UINT_FOR[upd.dtype.itemsize]
-    bits = jax.lax.population_count(jax.lax.bitcast_convert_type(upd, udt))
-    cnt = jnp.sum(bits.astype(jnp.uint32), dtype=jnp.uint32)
-    cnt_ref[...] = cnt.reshape(1, 1)
-    ok_ref[...] = (cnt == exp_ref[0, 0]).astype(jnp.int32).reshape(1, 1)
+    cnt = block_reduce(jax.lax.population_count(as_words(upd)))  # (1, 1)
+    cnt_ref[...] = jnp.broadcast_to(cnt, (1, LANES))[None]
     out_ref[...] = upd
 
 
@@ -56,29 +52,28 @@ def apply_unpack_blocked(base: jax.Array, packed: jax.Array,
     actual popcount. ``idx`` must not contain duplicates (each
     destination block written once).
     """
-    nblocks, rows, lanes = base.shape
     k = packed.shape[0]
-    assert lanes == LANES and packed.shape[1:] == (rows, lanes)
-    assert packed.dtype == base.dtype and base.dtype.itemsize in _UINT_FOR
+    assert packed.shape[1:] == base.shape[1:] and packed.dtype == base.dtype
     assert idx.shape == (k,) and expected.shape == (k,)
-    blk = pl.BlockSpec((1, rows, LANES), lambda i, idx: (i, 0, 0))
-    col = pl.BlockSpec((1, 1), lambda i, idx: (i, 0))
-    dst = pl.BlockSpec((1, rows, LANES), lambda i, idx: (idx[i], 0, 0))
-    out, ok, cnt = pl.pallas_call(
+    rows = packed.shape[1:]
+    blk = pl.BlockSpec((1,) + rows, lambda i, idx: (i, 0, 0))
+    dst = pl.BlockSpec((1,) + rows, lambda i, idx: (idx[i], 0, 0))
+    cnt_spec = pl.BlockSpec((1, 1, LANES), lambda i, idx: (i, 0, 0))
+    out, cnt = pl.pallas_call(
         _apply_unpack_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(k,),
-            in_specs=[blk, col, dst],
-            out_specs=[dst, col, col],
+            in_specs=[blk, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[dst, cnt_spec],
         ),
         out_shape=[
             jax.ShapeDtypeStruct(base.shape, base.dtype),
-            jax.ShapeDtypeStruct((k, 1), jnp.int32),
-            jax.ShapeDtypeStruct((k, 1), jnp.uint32),
+            jax.ShapeDtypeStruct((k, 1, LANES), jnp.int32),
         ],
-        input_output_aliases={3: 0},  # base (after the scalar operand) → out
+        input_output_aliases={2: 0},  # base (after the scalar operand) → out
         interpret=interpret,
-    )(idx.astype(jnp.int32), packed,
-      expected.astype(jnp.uint32).reshape(k, 1), base)
-    return out, ok[:, 0], cnt[:, 0]
+    )(idx.astype(jnp.int32), packed, base)
+    counts = cnt[:, 0, 0].astype(jnp.uint32)
+    ok = (counts == expected.astype(jnp.uint32)).astype(jnp.int32)
+    return out, ok, counts

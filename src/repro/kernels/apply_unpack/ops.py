@@ -10,22 +10,18 @@ popcount-verify → copy chain (two reads of the restored image).
 
 from __future__ import annotations
 
+import functools
 from typing import Literal, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.blocks import TPU_TILE
-from repro.kernels.common import LANES, as_blocks, from_blocks
+from repro.kernels.common import LANES, as_blocks, from_blocks, resolve_impl
 from repro.kernels.apply_unpack.kernel import apply_unpack_blocked
 from repro.kernels.apply_unpack.ref import apply_unpack_blocked_ref
 
-Impl = Literal["auto", "pallas", "fused", "ref"]
-
-#: the oracle is jitted so the off-TPU fallback is still ONE dispatch per
-#: buffer (popcount+scatter fused by XLA) — the staged restore chain pays
-#: a verify dispatch plus a copy pass per buffer
-_ref_jit = jax.jit(apply_unpack_blocked_ref)
+Impl = Literal["auto", "pallas", "fused", "interpret", "ref"]
 
 
 class ApplyUnpack(NamedTuple):
@@ -45,6 +41,26 @@ class ApplyUnpack(NamedTuple):
     nbad: int
 
 
+@functools.partial(jax.jit, static_argnames=("block_bytes", "impl"))
+def apply_unpack_device(base: jax.Array, packed: jax.Array, index: jax.Array,
+                        expected: jax.Array, *, block_bytes: int, impl: str):
+    """The whole device side of :func:`apply_unpack` as ONE dispatch (the
+    oracle is jitted too: popcount+scatter fused by XLA) → (out, ok,
+    counts). ``impl`` is a resolved implementation: ``"pallas"``,
+    ``"interpret"`` or ``"ref"``."""
+    k = index.shape[0]
+    packed_b = packed.reshape(k, -1, LANES)
+    base_b, orig_len = as_blocks(base, block_bytes)
+    idx = index.astype(jnp.int32)
+    exp = expected.astype(jnp.uint32)
+    if impl == "ref":
+        out_b, ok, counts = apply_unpack_blocked_ref(base_b, packed_b, idx, exp)
+    else:
+        out_b, ok, counts = apply_unpack_blocked(
+            base_b, packed_b, idx, exp, interpret=impl == "interpret")
+    return from_blocks(out_b, orig_len).reshape(base.shape), ok, counts
+
+
 def apply_unpack(base: jax.Array, packed: jax.Array, index, expected, *,
                  block_bytes: int = TPU_TILE,
                  impl: Impl = "auto") -> ApplyUnpack:
@@ -53,9 +69,11 @@ def apply_unpack(base: jax.Array, packed: jax.Array, index, expected, *,
     ``packed`` holds k consecutive blocks (``k * block_bytes`` bytes);
     ``index`` (k,) names each block's destination block of ``base``
     (duplicate-free); ``expected`` (k,) uint32 holds the popcounts to
-    verify against. ``impl="fused"`` is an alias for ``"pallas"`` (the
-    fused kernel IS the pallas path); ``"auto"`` picks pallas on TPU and
-    the jnp oracle elsewhere, like every other kernel in this package.
+    verify against. ``impl`` as in
+    :func:`repro.kernels.common.resolve_impl`: ``"auto"`` runs the
+    compiled kernel on TPU and the jnp oracle elsewhere; ``"pallas"``
+    (alias ``"fused"`` — the fused kernel IS the pallas path) interprets
+    the kernel off the TPU.
     """
     if packed.dtype != base.dtype:
         raise ValueError("base and packed must share a dtype")
@@ -68,17 +86,10 @@ def apply_unpack(base: jax.Array, packed: jax.Array, index, expected, *,
     if k == 0:
         return ApplyUnpack(base, jnp.zeros((0,), jnp.int32),
                            jnp.zeros((0,), jnp.uint32), 0)
-    rows = elems // LANES
-    packed_b = jnp.asarray(packed).reshape(k, rows, LANES)
-    idx = jnp.asarray(index, dtype=jnp.int32)
-    exp = jnp.asarray(expected, dtype=jnp.uint32)
-    base_b, orig_len = as_blocks(base, block_bytes)
-    if impl == "ref" or (impl == "auto" and jax.default_backend() != "tpu"):
-        out_b, ok, counts = _ref_jit(base_b, packed_b, idx, exp)
-    else:
-        interpret = jax.default_backend() != "tpu"
-        out_b, ok, counts = apply_unpack_blocked(
-            base_b, packed_b, idx, exp, interpret=interpret)
-    out = from_blocks(out_b, orig_len).reshape(base.shape)
+    out, ok, counts = apply_unpack_device(
+        jnp.asarray(base), jnp.asarray(packed),
+        jnp.asarray(index, dtype=jnp.int32),
+        jnp.asarray(expected, dtype=jnp.uint32),
+        block_bytes=block_bytes, impl=resolve_impl(impl))
     nbad = int(k - jnp.sum(ok))
     return ApplyUnpack(out, ok, counts, nbad)

@@ -14,14 +14,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-_UINT_FOR = {4: jnp.uint32, 2: jnp.uint16, 1: jnp.uint8}
+from repro.kernels.common import as_bits
 
 
 def block_popcounts(packed: jax.Array) -> jax.Array:
     """(k, rows, 128) → (k,) uint32 per-block popcounts."""
-    udt = _UINT_FOR[packed.dtype.itemsize]
-    bits = jax.lax.population_count(
-        jax.lax.bitcast_convert_type(packed, udt))
+    bits = jax.lax.population_count(as_bits(packed))
     return jnp.sum(bits.astype(jnp.uint32), axis=(1, 2), dtype=jnp.uint32)
 
 

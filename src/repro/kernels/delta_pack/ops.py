@@ -14,18 +14,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.blocks import TPU_TILE
-from repro.kernels.common import as_blocks, from_blocks
+from repro.kernels.common import as_blocks, from_blocks, resolve_impl
 from repro.kernels.delta_pack.kernel import delta_apply_blocked, delta_pack_blocked
 from repro.kernels.delta_pack.ref import (
     delta_apply_blocked_ref,
     delta_pack_blocked_ref,
 )
 
-Impl = Literal["auto", "pallas", "ref"]
-
-
-def _use_ref(impl: Impl) -> bool:
-    return impl == "ref" or (impl == "auto" and jax.default_backend() != "tpu")
+Impl = Literal["auto", "pallas", "interpret", "ref"]
 
 
 def pack_delta(
@@ -37,9 +33,10 @@ def pack_delta(
 ) -> jax.Array:
     """Gather blocks ``idx`` of a flat buffer → (k, rows, 128) compact delta."""
     blocked, _ = as_blocks(buf, block_bytes)
-    if _use_ref(impl):
+    ran = resolve_impl(impl)
+    if ran == "ref":
         return delta_pack_blocked_ref(blocked, idx)
-    return delta_pack_blocked(blocked, idx, interpret=jax.default_backend() != "tpu")
+    return delta_pack_blocked(blocked, idx, interpret=ran == "interpret")
 
 
 def pack_dirty(
@@ -77,9 +74,10 @@ def apply_delta(
     """Scatter a packed delta back into a flat buffer; returns the new buffer
     (same shape/dtype as ``buf``)."""
     blocked, n = as_blocks(buf, block_bytes)
-    if _use_ref(impl):
+    ran = resolve_impl(impl)
+    if ran == "ref":
         out = delta_apply_blocked_ref(blocked, delta, idx)
     else:
         out = delta_apply_blocked(blocked, delta, idx,
-                                  interpret=jax.default_backend() != "tpu")
+                                  interpret=ran == "interpret")
     return from_blocks(out, n).reshape(buf.shape)

@@ -1,23 +1,25 @@
 """Public op: dirty-block bitmap of a flat parameter buffer.
 
-Dispatch: Pallas kernel on TPU (or ``impl='pallas'`` which uses interpret
-mode off-TPU — used by the test suite), pure-jnp reference otherwise. Both
-paths share padding/reshape via :mod:`repro.kernels.common`.
+Dispatch per :func:`repro.kernels.common.resolve_impl`: the compiled Pallas
+kernel on TPU, the Pallas interpreter on request (``impl="pallas"`` or
+``"interpret"`` off-TPU — used by the test suite), the pure-jnp reference
+otherwise. Both paths share padding/reshape via :mod:`repro.kernels.common`.
 """
 
 from __future__ import annotations
 
-from typing import Literal, Optional
+from typing import Literal
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.blocks import TPU_TILE
-from repro.kernels.common import TILE_BLOCKS, as_blocks, pad_blocks_to_tile
+from repro.kernels.common import (TILE_BLOCKS, as_blocks, pad_blocks_to_tile,
+                                  resolve_impl)
 from repro.kernels.dirty_diff.kernel import dirty_diff_blocked
 from repro.kernels.dirty_diff.ref import dirty_diff_blocked_ref
 
-Impl = Literal["auto", "pallas", "ref"]
+Impl = Literal["auto", "pallas", "interpret", "ref"]
 
 
 def dirty_blocks(
@@ -37,13 +39,13 @@ def dirty_blocks(
     cur_b, _ = as_blocks(cur, block_bytes)
     snap_b, _ = as_blocks(snap, block_bytes)
     nblocks = cur_b.shape[0]
-    if impl == "ref" or (impl == "auto" and jax.default_backend() != "tpu"):
+    ran = resolve_impl(impl)
+    if ran == "ref":
         return dirty_diff_blocked_ref(cur_b, snap_b)
-    interpret = jax.default_backend() != "tpu"
     padded = pad_blocks_to_tile(nblocks, TILE_BLOCKS)
     if padded != nblocks:
         pad = ((0, padded - nblocks), (0, 0), (0, 0))
         cur_b = jnp.pad(cur_b, pad)
         snap_b = jnp.pad(snap_b, pad)
-    flags = dirty_diff_blocked(cur_b, snap_b, interpret=interpret)
+    flags = dirty_diff_blocked(cur_b, snap_b, interpret=ran == "interpret")
     return flags[:nblocks]

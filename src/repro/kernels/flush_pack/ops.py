@@ -9,21 +9,18 @@ delta_pack chain.
 
 from __future__ import annotations
 
+import functools
 from typing import Literal, NamedTuple
 
 import jax
+import jax.numpy as jnp
 
 from repro.core.blocks import TPU_TILE
-from repro.kernels.common import as_blocks, blocked_for_tiles
+from repro.kernels.common import as_blocks, blocked_for_tiles, resolve_impl
 from repro.kernels.flush_pack.kernel import flush_pack_blocked
 from repro.kernels.flush_pack.ref import flush_pack_blocked_ref
 
-Impl = Literal["auto", "pallas", "fused", "ref"]
-
-#: the oracle is jitted so the off-TPU fallback is still ONE dispatch per
-#: buffer (diff+popcount+compaction+pack fused by XLA) — the save path's
-#: staged chain pays three dispatches and a host round-trip per buffer
-_ref_jit = jax.jit(flush_pack_blocked_ref)
+Impl = Literal["auto", "pallas", "fused", "interpret", "ref"]
 
 
 class FlushPack(NamedTuple):
@@ -48,32 +45,36 @@ class FlushPack(NamedTuple):
     total: int
 
 
+@functools.partial(jax.jit, static_argnames=("block_bytes", "impl"))
+def flush_pack_device(cur: jax.Array, snap: jax.Array, *, block_bytes: int,
+                      impl: str):
+    """The whole device side of :func:`flush_pack` as ONE dispatch (the
+    oracle is jitted too, so the off-TPU path is also a single fused XLA
+    program) → (flags, counts, offsets, packed, index). ``impl`` is a
+    resolved implementation: ``"pallas"``, ``"interpret"`` or ``"ref"``."""
+    if impl == "ref":
+        return flush_pack_blocked_ref(as_blocks(cur, block_bytes)[0],
+                                      as_blocks(snap, block_bytes)[0])
+    cur_b, nblocks, _ = blocked_for_tiles(cur, block_bytes)
+    snap_b, _, _ = blocked_for_tiles(snap, block_bytes)
+    outs = flush_pack_blocked(cur_b, snap_b, interpret=impl == "interpret")
+    return tuple(o[:nblocks] for o in outs)
+
+
 def flush_pack(cur: jax.Array, snap: jax.Array, *,
                block_bytes: int = TPU_TILE,
                impl: Impl = "auto") -> FlushPack:
     """Fused diff+pack+checksum of flat ``cur`` vs ``snap`` → FlushPack.
 
-    ``impl="fused"`` is an alias for ``"pallas"`` (the fused kernel IS
-    the pallas path); ``"auto"`` picks pallas on TPU and the jnp oracle
-    elsewhere, like every other kernel in this package.
+    ``impl`` as in :func:`repro.kernels.common.resolve_impl`: ``"auto"``
+    runs the compiled kernel on TPU and the jnp oracle elsewhere;
+    ``"pallas"`` (alias ``"fused"`` — the fused kernel IS the pallas
+    path) interprets the kernel off the TPU.
     """
     if cur.shape != snap.shape or cur.dtype != snap.dtype:
         raise ValueError("cur and snap must match in shape and dtype")
-    if impl == "ref" or (impl == "auto" and jax.default_backend() != "tpu"):
-        cur_b, _ = as_blocks(cur, block_bytes)
-        snap_b, _ = as_blocks(snap, block_bytes)
-        nblocks = cur_b.shape[0]
-        flags, counts, off, packed, index = _ref_jit(cur_b, snap_b)
-    else:
-        interpret = jax.default_backend() != "tpu"
-        cur_b, nblocks, _ = blocked_for_tiles(cur, block_bytes)
-        snap_b, _, _ = blocked_for_tiles(snap, block_bytes)
-        flags, counts, off, packed, index = flush_pack_blocked(
-            cur_b, snap_b, interpret=interpret)
-        flags = flags[:nblocks]
-        counts = counts[:nblocks]
-        off = off[:nblocks]
-        packed = packed[:nblocks]
-        index = index[:nblocks]
-    total = int(off[-1] + flags[-1]) if nblocks else 0
+    flags, counts, off, packed, index = flush_pack_device(
+        jnp.asarray(cur), jnp.asarray(snap), block_bytes=block_bytes,
+        impl=resolve_impl(impl))
+    total = int(off[-1] + flags[-1])
     return FlushPack(flags, counts, off, packed, index, total)

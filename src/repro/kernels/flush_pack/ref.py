@@ -17,7 +17,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-_UINT_FOR = {4: jnp.uint32, 2: jnp.uint16, 1: jnp.uint8}
+from repro.kernels.common import as_bits
 
 
 def exclusive_prefix_sum(flags: jax.Array) -> jax.Array:
@@ -61,15 +61,12 @@ def flush_pack_blocked_ref(cur: jax.Array, snap: jax.Array):
     zero operand before updating, a third pass over the data).
     """
     nblocks = cur.shape[0]
-    flags = jnp.any(cur != snap, axis=(1, 2)).astype(jnp.int32)
-    udt = _UINT_FOR[cur.dtype.itemsize]
-    bits = jax.lax.population_count(jax.lax.bitcast_convert_type(cur, udt))
-    counts = jnp.sum(bits.astype(jnp.uint32), axis=(1, 2), dtype=jnp.uint32)
+    bits = as_bits(cur)
+    flags = jnp.any(bits != as_bits(snap), axis=(1, 2)).astype(jnp.int32)
+    counts = jnp.sum(jax.lax.population_count(bits).astype(jnp.uint32),
+                     axis=(1, 2), dtype=jnp.uint32)
     offsets = exclusive_prefix_sum(flags)
-    dst = jnp.where(flags > 0, offsets, nblocks)
-    index = jnp.zeros((nblocks + 1,), jnp.int32).at[dst].set(
-        jnp.arange(nblocks, dtype=jnp.int32))[:nblocks]
-    total = offsets[-1] + flags[-1]
+    index, total = compact_index(flags)
     live = jnp.arange(nblocks, dtype=jnp.int32) < total
     packed = jnp.where(live[:, None, None], jnp.take(cur, index, axis=0),
                        jnp.zeros((), cur.dtype))

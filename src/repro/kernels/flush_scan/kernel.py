@@ -9,8 +9,8 @@ the device-side cost of a delta-checkpoint scan drops from 3 buffer-reads
 to 2 — a 1.5× cut of the dominant term of the save path (EXPERIMENTS.md
 §Perf, persistence numbers).
 
-Grid: one program per TILE_BLOCKS blocks; outputs per-block
-(dirty int32, popcount uint32) vectors.
+Grid: one program per TILE_BLOCKS blocks (compared as int32 words);
+outputs per-block (dirty int32, popcount uint32) vectors.
 """
 
 from __future__ import annotations
@@ -21,19 +21,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import LANES, TILE_BLOCKS
-
-_UINT_FOR = {4: jnp.uint32, 2: jnp.uint16, 1: jnp.uint8}
+from repro.kernels.common import TILE_BLOCKS, as_words, block_reduce
 
 
 def _flush_scan_kernel(cur_ref, snap_ref, dirty_ref, cnt_ref):
-    cur = cur_ref[...]
-    snap = snap_ref[...]
-    dirty_ref[...] = jnp.any(cur != snap, axis=(1, 2)).astype(jnp.int32)[:, None]
-    udt = _UINT_FOR[cur.dtype.itemsize]
-    bits = jax.lax.population_count(jax.lax.bitcast_convert_type(cur, udt))
-    cnt_ref[...] = jnp.sum(bits.astype(jnp.uint32), axis=(1, 2),
-                           dtype=jnp.uint32)[:, None]
+    cur = as_words(cur_ref[...])
+    dirty_ref[...] = block_reduce(
+        (cur != as_words(snap_ref[...])).astype(jnp.int32), jnp.max)
+    cnt_ref[...] = block_reduce(jax.lax.population_count(cur))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -41,22 +36,20 @@ def flush_scan_blocked(cur: jax.Array, snap: jax.Array, *,
                        interpret: bool = False):
     """(nblocks, rows, 128) ×2 → ((nblocks,) int32 dirty, (nblocks,) uint32
     popcounts), one pass."""
-    nblocks, rows, lanes = cur.shape
-    assert lanes == LANES and cur.shape == snap.shape
+    nblocks = cur.shape[0]
+    assert cur.shape == snap.shape
     assert nblocks % TILE_BLOCKS == 0
-    assert cur.dtype.itemsize in _UINT_FOR
-    grid = (nblocks // TILE_BLOCKS,)
-    spec = pl.BlockSpec((TILE_BLOCKS, rows, LANES), lambda i: (i, 0, 0))
+    spec = pl.BlockSpec((TILE_BLOCKS,) + cur.shape[1:], lambda i: (i, 0, 0))
     out_spec = pl.BlockSpec((TILE_BLOCKS, 1), lambda i: (i, 0))
     dirty, cnt = pl.pallas_call(
         _flush_scan_kernel,
-        grid=grid,
+        grid=(nblocks // TILE_BLOCKS,),
         in_specs=[spec, spec],
         out_specs=[out_spec, out_spec],
         out_shape=[
             jax.ShapeDtypeStruct((nblocks, 1), jnp.int32),
-            jax.ShapeDtypeStruct((nblocks, 1), jnp.uint32),
+            jax.ShapeDtypeStruct((nblocks, 1), jnp.int32),
         ],
         interpret=interpret,
     )(cur, snap)
-    return dirty[:, 0], cnt[:, 0]
+    return dirty[:, 0], cnt[:, 0].astype(jnp.uint32)

@@ -12,11 +12,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.blocks import TPU_TILE
-from repro.kernels.common import TILE_BLOCKS, as_blocks, pad_blocks_to_tile
+from repro.kernels.common import (TILE_BLOCKS, as_blocks, pad_blocks_to_tile,
+                                  resolve_impl)
 from repro.kernels.flush_scan.kernel import flush_scan_blocked
 from repro.kernels.flush_scan.ref import flush_scan_blocked_ref
 
-Impl = Literal["auto", "pallas", "ref"]
+Impl = Literal["auto", "pallas", "interpret", "ref"]
 
 
 def flush_scan(cur: jax.Array, snap: jax.Array, *,
@@ -28,13 +29,14 @@ def flush_scan(cur: jax.Array, snap: jax.Array, *,
     cur_b, _ = as_blocks(cur, block_bytes)
     snap_b, _ = as_blocks(snap, block_bytes)
     nblocks = cur_b.shape[0]
-    if impl == "ref" or (impl == "auto" and jax.default_backend() != "tpu"):
+    ran = resolve_impl(impl)
+    if ran == "ref":
         return flush_scan_blocked_ref(cur_b, snap_b)
-    interpret = jax.default_backend() != "tpu"
     padded = pad_blocks_to_tile(nblocks, TILE_BLOCKS)
     if padded != nblocks:
         pad = ((0, padded - nblocks), (0, 0), (0, 0))
         cur_b = jnp.pad(cur_b, pad)
         snap_b = jnp.pad(snap_b, pad)
-    dirty, cnt = flush_scan_blocked(cur_b, snap_b, interpret=interpret)
+    dirty, cnt = flush_scan_blocked(cur_b, snap_b,
+                                    interpret=ran == "interpret")
     return dirty[:nblocks], cnt[:nblocks]

@@ -5,13 +5,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-_UINT_FOR = {4: jnp.uint32, 2: jnp.uint16, 1: jnp.uint8}
+from repro.kernels.common import as_bits
 
 
 def flush_scan_blocked_ref(cur: jax.Array, snap: jax.Array):
     """(nblocks, rows, 128) ×2 → per-block (dirty flags, popcounts)."""
-    dirty = jnp.any(cur != snap, axis=(1, 2)).astype(jnp.int32)
-    udt = _UINT_FOR[cur.dtype.itemsize]
-    bits = jax.lax.population_count(jax.lax.bitcast_convert_type(cur, udt))
-    cnt = jnp.sum(bits.astype(jnp.uint32), axis=(1, 2), dtype=jnp.uint32)
+    bits = as_bits(cur)
+    dirty = jnp.any(bits != as_bits(snap), axis=(1, 2)).astype(jnp.int32)
+    cnt = jnp.sum(jax.lax.population_count(bits).astype(jnp.uint32),
+                  axis=(1, 2), dtype=jnp.uint32)
     return dirty, cnt

@@ -6,43 +6,39 @@ the data just to checksum it)."""
 
 from __future__ import annotations
 
+import functools
 from typing import Literal
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.blocks import TPU_TILE
-from repro.kernels.common import TILE_BLOCKS, as_blocks, pad_blocks_to_tile
+from repro.kernels.common import (TILE_BLOCKS, as_blocks, pad_blocks_to_tile,
+                                  resolve_impl)
 from repro.kernels.popcnt_checksum.kernel import popcnt_blocked
 from repro.kernels.popcnt_checksum.ref import popcnt_blocked_ref
 
-Impl = Literal["auto", "pallas", "ref"]
+Impl = Literal["auto", "pallas", "interpret", "ref"]
 
 
-def _as_u32(x: jax.Array) -> jax.Array:
-    """Bitcast any dtype to uint32 (pad to 4-byte multiple via uint8)."""
-    if x.dtype == jnp.uint32:
-        return x.reshape(-1)
-    b = jax.lax.bitcast_convert_type(x.reshape(-1), jnp.uint8).reshape(-1)
-    pad = (-b.shape[0]) % 4
-    if pad:
-        b = jnp.pad(b, (0, pad))
-    return jax.lax.bitcast_convert_type(b.reshape(-1, 4), jnp.uint32).reshape(-1)
+@functools.partial(jax.jit, static_argnames=("block_bytes", "impl"))
+def _popcount_blocks(x: jax.Array, *, block_bytes: int, impl: str) -> jax.Array:
+    xb, _ = as_blocks(x, block_bytes)
+    nblocks = xb.shape[0]
+    if impl == "ref":
+        return popcnt_blocked_ref(xb)
+    padded = pad_blocks_to_tile(nblocks, TILE_BLOCKS)
+    if padded != nblocks:
+        xb = jnp.pad(xb, ((0, padded - nblocks), (0, 0), (0, 0)))
+    return popcnt_blocked(xb, interpret=impl == "interpret")[:nblocks]
 
 
 def popcount_blocks(x: jax.Array, *, block_bytes: int = TPU_TILE,
                     impl: Impl = "auto") -> jax.Array:
-    """(nblocks,) uint32 per-block popcounts of a flat buffer."""
-    u32 = _as_u32(x)
-    xb, _ = as_blocks(u32, block_bytes)
-    nblocks = xb.shape[0]
-    if impl == "ref" or (impl == "auto" and jax.default_backend() != "tpu"):
-        return popcnt_blocked_ref(xb)
-    interpret = jax.default_backend() != "tpu"
-    padded = pad_blocks_to_tile(nblocks, TILE_BLOCKS)
-    if padded != nblocks:
-        xb = jnp.pad(xb, ((0, padded - nblocks), (0, 0), (0, 0)))
-    return popcnt_blocked(xb, interpret=interpret)[:nblocks]
+    """(nblocks,) uint32 per-block popcounts of a flat buffer (one
+    dispatch; ``impl`` as in :func:`repro.kernels.common.resolve_impl`)."""
+    return _popcount_blocks(jnp.asarray(x), block_bytes=block_bytes,
+                            impl=resolve_impl(impl))
 
 
 def popcount_checksum(x: jax.Array, *, impl: Impl = "auto") -> jax.Array:

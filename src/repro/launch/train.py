@@ -7,7 +7,9 @@ checkpoints flushed asynchronously every --ckpt-every steps, crash
 recovery on restart (checkpoint + WAL fast-forward = exactly-once steps).
 
 CPU-runnable: reduced configs train a real model for hundreds of steps
-(examples/train_tinyllama.py); full configs are exercised by the dry-run.
+(examples/train_tinyllama.py). Full configs train on one TPU chip where
+they fit: ``chip_smoke.py`` runs mamba2-130m at its published widths
+through save → kill → resume.
 
   PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
       --reduced --steps 200 --batch 8 --seq 128 --out /tmp/run1
@@ -29,6 +31,7 @@ import numpy as np
 from repro.configs import get_config, get_reduced
 from repro.data import SyntheticPipeline
 from repro.pool import Pool
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.steps import build_train_step
 from repro.models import init_params
 from repro.optim import AdamWConfig, adamw_init
@@ -128,25 +131,30 @@ class Trainer:
 
         self.start_step = 0
         params = opt_state = None
-        if tc.resume and os.path.exists(os.path.join(tc.out, "ckpt.pmem")) \
-                and os.path.getsize(os.path.join(tc.out, "ckpt.pmem")) > 0:
+        ckpt_path = os.path.join(tc.out, "ckpt.pmem")
+        if tc.resume and os.path.exists(ckpt_path) \
+                and os.path.getsize(ckpt_path) > 0:
+            # a checkpoint file that does not restore is an error, never a
+            # silent fresh start over the run's committed state
             try:
                 step, flat = self.manager.restore()
-                tmpl_p = jax.eval_shape(lambda k: init_params(self.cfg, k),
-                                        jax.random.key(0))
-                tmpl_o = jax.eval_shape(adamw_init, tmpl_p)
-                np_params = {k[2:]: v for k, v in flat.items() if k.startswith("p/")}
-                np_opt = {k[2:]: v for k, v in flat.items() if k.startswith("o/")}
-                params = unflatten_like(tmpl_p, np_params)
-                opt_state = unflatten_like(tmpl_o, np_opt)
-                self.start_step = step
-                print(f"[train] restored checkpoint @ step {step}")
-                if self.wal.last is not None and self.wal.last.step > step:
-                    print(f"[train] WAL ahead at step {self.wal.last.step}; "
-                          f"fast-forwarding data cursor")
-                    self.start_step = step  # deterministic replay from ckpt
-            except FileNotFoundError:
-                pass
+            except (FileNotFoundError, RuntimeError) as e:
+                raise RuntimeError(
+                    f"{ckpt_path} holds no checkpoint that restores ({e}); "
+                    f"remove it or set resume=False to start afresh") from e
+            tmpl_p = jax.eval_shape(lambda k: init_params(self.cfg, k),
+                                    jax.random.key(0))
+            tmpl_o = jax.eval_shape(adamw_init, tmpl_p)
+            np_params = {k[2:]: v for k, v in flat.items() if k.startswith("p/")}
+            np_opt = {k[2:]: v for k, v in flat.items() if k.startswith("o/")}
+            params = unflatten_like(tmpl_p, np_params)
+            opt_state = unflatten_like(tmpl_o, np_opt)
+            self.start_step = step
+            print(f"[train] restored checkpoint @ step {step}")
+            if self.wal.last is not None and self.wal.last.step > step:
+                print(f"[train] WAL ahead at step {self.wal.last.step}; "
+                      f"fast-forwarding data cursor")
+                self.start_step = step  # deterministic replay from ckpt
         if params is None:
             params = init_params(self.cfg, jax.random.key(0))
             opt_state = adamw_init(params)
@@ -222,6 +230,7 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--no-resume", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
     tc = TrainerConfig(arch=args.arch, reduced=args.reduced, steps=args.steps,
                        batch=args.batch, seq=args.seq,
                        ckpt_every=args.ckpt_every, out=args.out, lr=args.lr,

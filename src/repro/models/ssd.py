@@ -99,9 +99,13 @@ def ssd_apply(
 
         # intra-chunk quadratic form
         scores = jnp.einsum("bcqn,bctn->bcqt", Cc, Bc)                # (B,c,Q,Q)
-        decay_qt = jnp.exp(cum[:, :, :, None] - cum[:, :, None, :])   # (B,c,Q,Q,H)
         causal = (jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :])
-        w_qt = scores[..., None] * decay_qt * dtc[:, :, None] * causal[None, None, :, :, None]
+        # mask BEFORE exp: for q < t, cum[q] - cum[t] > 0 grows with the
+        # chunk's decay and overflows to inf, and inf · 0 is NaN
+        seg = jnp.where(causal[None, None, :, :, None],
+                        cum[:, :, :, None] - cum[:, :, None, :], -jnp.inf)
+        decay_qt = jnp.exp(seg)                                       # (B,c,Q,Q,H)
+        w_qt = scores[..., None] * decay_qt * dtc[:, :, None]
         y_intra = jnp.einsum("bcqth,bcthp->bcqhp", w_qt, xc)
 
         # chunk end-states

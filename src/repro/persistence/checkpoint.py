@@ -59,6 +59,7 @@ from repro.core.persist import AccessPattern, FlushKind
 from repro.core.pmem import PMem, PMemStats
 from repro.pool import LogHandle, PagesHandle, Pool
 from repro.kernels.apply_unpack import apply_unpack
+from repro.kernels.common import resolve_impl
 from repro.kernels.dirty_diff import dirty_blocks
 from repro.kernels.flush_pack import compact_index, flush_pack
 from repro.kernels.popcnt_checksum import popcount_blocks
@@ -82,14 +83,16 @@ class CheckpointConfig:
     delta: bool = True               # enable µLog shadow-slot deltas
     threads: int = 1                 # writer threads (G4: bounded; feeds policy)
     #: scan-kernel dispatch, BOTH directions. Save:
-    #: "auto"/"fused"/"pallas"/"ref" run the one-pass flush_pack kernel
-    #: (auto = pallas on TPU, jnp oracle off); "staged" keeps the
+    #: "auto"/"fused"/"pallas"/"interpret"/"ref" run the one-pass
+    #: flush_pack kernel (auto = compiled pallas on TPU, jnp oracle off;
+    #: pallas/fused off the TPU = interpreted); "staged" keeps the
     #: pre-fusion dirty_diff → popcnt → compaction chain (three
     #: live-buffer reads) for A/B benchmarks and the crash corpus'
     #: byte-parity case. Restore: the same values route the one-pass
     #: apply_unpack kernel (verify+scatter+apply, one read of the
     #: restored image) vs the staged popcount-verify → copy chain (two
-    #: reads) — staged and fused recover bit-identical state.
+    #: reads) — staged and fused recover bit-identical state. Reports
+    #: record what actually ran (:attr:`CheckpointManager.scan_impl`).
     kernel_impl: str = "auto"
     extra_slots: int = 4             # beyond the 2-per-page steady state
     #: PMem page-slot budget for the shard. None = classic sizing (two
@@ -116,6 +119,14 @@ class CheckpointConfig:
     cache_frames: Optional[int] = None
     #: k-touch SSD→PMem promotion threshold for the shard's pages
     cache_admit_k: int = 2
+
+    def __post_init__(self) -> None:
+        # pages are whole dirty-tracking units, and the scan kernels view
+        # a page as whole (8, 128) int32 tiles
+        unit = self.geometry.cache_line
+        if self.page_size <= 0 or self.page_size % unit:
+            raise ValueError(f"page_size={self.page_size} is not a positive "
+                             f"multiple of the {unit}-byte dirty unit")
 
     @property
     def geometry(self) -> BlockGeometry:
@@ -148,6 +159,9 @@ class SaveReport:
     scan_read_bytes: int = 0
     #: modeled device time of that scan traffic (included in modeled_ns)
     scan_ns: float = 0.0
+    #: what ran the scan: "pallas" (compiled), "interpret", "ref" or
+    #: "staged" (see :attr:`CheckpointManager.scan_impl`)
+    kernel_impl: str = ""
 
     @property
     def bytes_device(self) -> int:
@@ -173,7 +187,8 @@ class RestoreReport:
     restore_read_bytes: int = 0
     scan_ns: float = 0.0
     modeled_ns: float = 0.0
-    kernel_impl: str = "auto"
+    #: what verified and assembled the pages, as in :class:`SaveReport`
+    kernel_impl: str = ""
 
 
 class CheckpointManager:
@@ -321,6 +336,15 @@ class CheckpointManager:
 
     # ------------------------------------------------------------- save
 
+    @property
+    def scan_impl(self) -> str:
+        """What runs this shard's save and restore scans: ``"staged"``
+        (the pre-fusion chain) or the fused kernels' implementation on
+        this backend — ``"pallas"`` (compiled), ``"interpret"`` or
+        ``"ref"`` (see :func:`repro.kernels.common.resolve_impl`)."""
+        impl = self.cfg.kernel_impl
+        return "staged" if impl == "staged" else resolve_impl(impl)
+
     def _note_scan(self, nbytes: int) -> None:
         """Attribute save-scan HBM traffic to the epoch being built (the
         flush queue folds it into the epoch's modeled time)."""
@@ -340,12 +364,13 @@ class CheckpointManager:
         buf = self._leaf_bytes(cur)
         snap = self._leaf_snapshot(name)
         cl = self.cfg.geometry.cache_line
-        impl = self.cfg.kernel_impl
+        impl = self.scan_impl
         jbuf = jax.numpy.asarray(buf)
         if snap is None or not self.cfg.delta:
+            # the staged chain's popcount pass dispatches like its others
             counts = np.asarray(popcount_blocks(
                 jbuf, block_bytes=cl,
-                impl="auto" if impl in ("fused", "staged") else impl))
+                impl="auto" if impl == "staged" else impl))
             self._note_scan(buf.size)   # full rewrite: one pass, no diff
             return None, buf, counts
         jsnap = jax.numpy.asarray(snap)
@@ -394,7 +419,7 @@ class CheckpointManager:
             raise ValueError("state keys changed between saves")
         cfg = self.cfg
         before: PMemStats = self.pmem.stats.snapshot()
-        report = SaveReport(step=step)
+        report = SaveReport(step=step, kernel_impl=self.scan_impl)
         entry: Dict[str, Any] = {"step": step, "shard": self.shard_id, "leaves": {}}
 
         # Pass 1 — dirty scan + page build: clean pages keep their slot,
@@ -569,7 +594,7 @@ class CheckpointManager:
         self._layout = self.pool.pages_layout("pages")
         img = self.pmem.durable_view()
         before: PMemStats = self.pmem.stats.snapshot()
-        report = RestoreReport(kernel_impl=cfg.kernel_impl)
+        report = RestoreReport(kernel_impl=self.scan_impl)
         self._restore_read_bytes = 0
         self._restore_pages_spilled = 0
         for raw in reversed(rec.entries):
@@ -604,7 +629,7 @@ class CheckpointManager:
         cfg = self.cfg
         state: Dict[str, np.ndarray] = {}
         layout = self._layout
-        staged = cfg.kernel_impl == "staged" or cfg.page_size % 128 != 0
+        staged = self.scan_impl == "staged"
         for name, meta in entry["leaves"].items():
             pages: List[Optional[np.ndarray]] = []
             spilled: List[Tuple[int, int, int]] = []   # (pos, pid, pvn)
@@ -677,7 +702,7 @@ class CheckpointManager:
         res = apply_unpack(base, packed,
                            np.arange(k, dtype=np.int32), expected,
                            block_bytes=cfg.page_size,
-                           impl=cfg.kernel_impl)
+                           impl=self.scan_impl)
         self._restore_read_bytes += k * cfg.page_size   # one pass, fused
         if verify and res.nbad:
             skip = np.asarray(csums, dtype=np.uint32) == 0

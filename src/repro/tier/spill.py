@@ -453,26 +453,33 @@ class SpillScheduler:
         PMem slot. See the module docstring for the crash argument."""
         layout = store.layout
         slot, pvn = store.table[pid]
-        data = store.pmem.load(layout.slot_data_off(slot), layout.page_size,
-                               uncached=True)
         prev = self._page_map.get((owner, pid))   # re-spill supersedes this
-        off = self._alloc(layout.page_size)
-        self.ssd.pwrite(off, data)
-        self._fp("page:ssd_written")
-        self.ssd.flush()
-        self._fp("page:ssd_flushed")
-        crc = zlib.crc32(data.tobytes()) & 0xFFFFFFFF
-        self._map_append(self._encode(
-            _REC_PAGE, owner, _PAGE.pack(pid, pvn, off, layout.page_size,
-                                         crc)))
-        self._fp("page:mapped")
-        if prev is not None:
-            # the new record durably superseded the old extent — reusable
-            self._free_extents.append((prev[0], prev[1]))
+        if prev is not None and prev[2] > pvn:
+            # a stale durable header that recovery found in a released
+            # slot: the SSD copy is the newer version (residency's
+            # max-pvn rule), so only the slot goes — spilling its bytes
+            # would overwrite the current version with the old one
+            pvn = prev[2]
+        else:
+            data = store.pmem.load(layout.slot_data_off(slot),
+                                   layout.page_size, uncached=True)
+            off = self._alloc(layout.page_size)
+            self.ssd.pwrite(off, data)
+            self._fp("page:ssd_written")
+            self.ssd.flush()
+            self._fp("page:ssd_flushed")
+            crc = zlib.crc32(data.tobytes()) & 0xFFFFFFFF
+            self._map_append(self._encode(
+                _REC_PAGE, owner, _PAGE.pack(pid, pvn, off, layout.page_size,
+                                             crc)))
+            self._fp("page:mapped")
+            if prev is not None:
+                # the new record durably superseded the old extent — reusable
+                self._free_extents.append((prev[0], prev[1]))
+            self.stats.pages_spilled += 1
         store.release(pid)
         store.pvn_floor[pid] = max(store.pvn_floor.get(pid, 0), pvn)
         self._last_use.pop((owner, pid), None)
-        self.stats.pages_spilled += 1
         cb = self._on_evict.get(owner)
         if cb is not None:
             cb(pid)

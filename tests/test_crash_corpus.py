@@ -333,6 +333,11 @@ CLUSTER_CORPUS = [
     (3, 4, 48, 8, 4, 7109, 0.5, True, 0.5),     # tiered source, own flip
     (3, 4, 48, 8, 5, 7110, 0.5, True, 0.0),     # tiered, SSD loses all
     (2, 3, 40, 10, 99, 7111, 0.5, False, 1.0),  # no crash: clean control
+    # a page spilled at pvn 5 left a stale pvn-4 header in a released PMem
+    # slot; recovery tables it, and the reads' promotions must not spill
+    # that old image over the newer SSD copy (the last put was lost)
+    (3, 4, 48, 8, 1, 4, 0.0, True, 0.0),        # tiered, crash at view start
+    (3, 4, 48, 8, 99, 4, 0.0, True, 1.0),       # tiered, no crash in reshard
 ]
 
 

@@ -221,6 +221,34 @@ def test_flush_pack_matches_staged_oracles(dtype):
     np.testing.assert_array_equal(np.asarray(restored), np.asarray(cur))
 
 
+@pytest.mark.parametrize("impl", ["ref", "pallas"])
+def test_dirty_is_a_byte_difference(impl):
+    """-0.0 == 0.0 as floats but not as bytes: a checkpoint that skipped
+    the block would restore the wrong bytes. Kernel and oracle agree."""
+    snap = jnp.zeros(4096, jnp.float32)
+    cur = snap.at[1500].set(-0.0)
+    want = [0, 1, 0, 0]
+    fp = flush_pack(cur, snap, impl=impl)
+    assert fp.total == 1
+    np.testing.assert_array_equal(np.asarray(fp.flags), want)
+    np.testing.assert_array_equal(
+        np.asarray(dirty_blocks(cur, snap, impl=impl)), want)
+
+
+def test_resolve_impl_names_what_runs():
+    """Off the TPU "auto" is the oracle and a request for the kernel is
+    the interpreter; nothing silently changes what a report records."""
+    from repro.kernels.common import resolve_impl
+    on_tpu = jax.default_backend() == "tpu"
+    kernel = "pallas" if on_tpu else "interpret"
+    assert resolve_impl("auto") == ("pallas" if on_tpu else "ref")
+    assert resolve_impl("pallas") == resolve_impl("fused") == kernel
+    assert resolve_impl("interpret") == "interpret"
+    assert resolve_impl("ref") == "ref"
+    with pytest.raises(ValueError):
+        resolve_impl("staged")
+
+
 def test_compact_index_matches_flatnonzero():
     """On-device prefix-sum compaction == np.flatnonzero, including the
     empty, full, and single-flag patterns."""

@@ -207,3 +207,57 @@ def test_mla_flash_matches_dense():
         att.FLASH_THRESHOLD = old
     np.testing.assert_allclose(np.asarray(out_flash), np.asarray(out_dense),
                                rtol=2e-4, atol=2e-4)
+
+
+def _ssd_sequential(p, x_in, cfg):
+    """Plain reference: the selective-SSM recurrence one position at a time,
+    in float32 at full matmul precision."""
+    from repro.models.ssd import _conv_causal, _dims
+    H, P, di, N = _dims(cfg)
+    B, S, _ = x_in.shape
+    with jax.default_matmul_precision("highest"):
+        proj = x_in @ p["w_in"]
+        z, xBC, dt_raw = jnp.split(proj, [di, 2 * di + 2 * N], axis=-1)
+        xBC, _ = _conv_causal(xBC, p["conv_w"], p["conv_b"], None)
+        x, B_, C_ = jnp.split(xBC, [di, di + N], axis=-1)
+        x = x.reshape(B, S, H, P)
+        dt = jax.nn.softplus(dt_raw + p["dt_bias"])
+        A = -jnp.exp(p["A_log"])
+        h = jnp.zeros((B, H, P, N))
+        ys = []
+        for t in range(S):
+            h = (jnp.exp(dt[:, t] * A)[..., None, None] * h
+                 + jnp.einsum("bh,bhp,bn->bhpn", dt[:, t], x[:, t], B_[:, t]))
+            ys.append(jnp.einsum("bn,bhpn->bhp", C_[:, t], h)
+                      + p["D_skip"][:, None] * x[:, t])
+        y = jnp.stack(ys, axis=1).reshape(B, S, di)
+        from repro.models.layers import rmsnorm
+        out = rmsnorm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
+        return out @ p["w_out"]
+
+
+def test_ssd_chunk_with_fast_decay_matches_the_recurrence():
+    """Within a chunk, exp(cum[q] - cum[t]) for q < t grows without bound
+    when dt·A is large (a long chunk of a fast-decaying head): the chunked
+    scan must mask those terms before exp, not after, or inf · 0 turns
+    the output and its gradient into NaN."""
+    from repro.models.ssd import ssd_apply, ssd_init
+    cfg = dataclasses.replace(get_reduced("mamba2_130m"), dtype="float32",
+                              chunk=64)
+    p = ssd_init(jax.random.key(3), cfg, dtype=jnp.float32)
+    p["A_log"] = jnp.full_like(p["A_log"], np.log(16.0))
+    p["dt_bias"] = jnp.full_like(p["dt_bias"], np.log(np.expm1(2.0)))
+    x = jax.random.normal(jax.random.key(4), (2, 64, cfg.d_model))
+
+    def run(p):
+        with jax.default_matmul_precision("highest"):
+            return ssd_apply(p, x, cfg=cfg)[0]
+
+    got = run(p)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_ssd_sequential(p, x, cfg)),
+                               rtol=1e-4, atol=1e-4)
+    grads = jax.grad(lambda p: jnp.sum(run(p) ** 2))(p)
+    for g in jax.tree.leaves(grads):
+        assert bool(jnp.isfinite(g).all())

@@ -189,6 +189,36 @@ def test_fused_full_rewrite_when_delta_disabled(tmp_path):
         np.testing.assert_array_equal(got[k], state[k])
 
 
+@pytest.mark.parametrize("impl", ["auto", "ref", "fused", "interpret",
+                                  "staged"])
+def test_reports_record_the_scan_that_ran(tmp_path, impl):
+    """SaveReport and RestoreReport name what ran the scans — compiled
+    pallas, interpret, ref or staged — not the configured request."""
+    import dataclasses
+    import jax
+    kernel = "pallas" if jax.default_backend() == "tpu" else "interpret"
+    want = {"auto": "pallas" if kernel == "pallas" else "ref", "ref": "ref",
+            "fused": kernel, "interpret": "interpret", "staged": "staged"}
+    cfg = dataclasses.replace(CFG, kernel_impl=impl)
+    path = str(tmp_path / "s.pmem")
+    m = CheckpointManager(path, cfg)
+    assert m.save(0, make_state(0)).kernel_impl == want[impl]   # full
+    assert m.save(1, make_state(1)).kernel_impl == want[impl]   # delta
+    r = CheckpointManager(path, cfg)
+    assert r.restore()[0] == 1
+    assert r.last_restore.kernel_impl == want[impl]
+
+
+def test_page_size_must_be_whole_dirty_units():
+    """Pages are whole 4 KiB dirty-tracking units (the fused restore
+    kernel verifies them as whole int32 tiles): anything else is refused
+    up front instead of switching the restore to another path."""
+    with pytest.raises(ValueError, match="page_size"):
+        CheckpointConfig(page_size=4096 + 128)
+    with pytest.raises(ValueError, match="page_size"):
+        CheckpointConfig(page_size=0)
+
+
 # -------------------------------------------------------------------- WAL
 
 def test_wal_zero_single_barrier_per_step():

@@ -2,6 +2,8 @@
 training job through crashes — WAL + delta checkpoints + recovery combine to
 exactly-once step semantics."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -121,3 +123,18 @@ def test_repeated_crash_recovery_cycles(tmp_path):
         t = MiniTrainer(path, wal_pm, ckpt_every=2)
         t.manager.restore()
         t.wal = TrainWAL(wal_pm, 0, wal_pm.size, recover=True)
+
+
+def test_trainer_resume_refuses_a_checkpoint_that_does_not_restore(tmp_path):
+    """A run directory whose checkpoint pool holds no committed checkpoint
+    is an error on resume, never a silent fresh start."""
+    from repro.launch.train import Trainer, TrainerConfig
+    tc = TrainerConfig(arch="mamba2-130m", reduced=True, steps=2, batch=2,
+                       seq=16, ckpt_every=1, out=str(tmp_path),
+                       async_flush=False)
+    # the pool exists, but its first save never committed a manifest
+    CheckpointManager(str(tmp_path / "ckpt.pmem"), CFG)._build(
+        {"w": np.zeros(1024, np.float32)})
+    with pytest.raises(RuntimeError, match="no checkpoint that restores"):
+        Trainer(tc)
+    assert Trainer(dataclasses.replace(tc, resume=False)).start_step == 0
