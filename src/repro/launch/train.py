@@ -42,6 +42,7 @@ from repro.persistence import (
     StepRecord,
     TrainWAL,
 )
+from repro.spans import compiles, span
 
 
 def flatten_state(tree, prefix="") -> Dict[str, np.ndarray]:
@@ -91,7 +92,17 @@ class TrainerConfig:
 
 
 class Trainer:
+    """The training loop and its persistence. Spans (:mod:`repro.spans`)
+    mark its phases: ``trainer.build`` (the constructor, with
+    ``trainer.wal_open`` and, on resume, the checkpoint's ``ckpt.restore``
+    and ``trainer.upload``), and per step ``train.step``,
+    ``train.wal_commit`` and, at a checkpoint, ``ckpt.stage``."""
+
     def __init__(self, tc: TrainerConfig) -> None:
+        with span("trainer.build"):
+            self._build(tc)
+
+    def _build(self, tc: TrainerConfig) -> None:
         self.tc = tc
         os.makedirs(tc.out, exist_ok=True)
         self.cfg = get_reduced(tc.arch) if tc.reduced else get_config(tc.arch)
@@ -100,30 +111,31 @@ class Trainer:
             self.cfg, AdamWConfig(lr=tc.lr), remat=tc.remat,
             total_steps=max(tc.steps, 100)))
         # --- persistence ------------------------------------------------
-        wal_path = os.path.join(tc.out, "wal.pmem")
-        wal_bytes = TrainWAL.capacity_for(tc.wal_capacity_steps,
-                                          lanes=tc.wal_lanes,
-                                          gen_sets=tc.wal_gen_sets)
-        if tc.wal_gen_sets > 1:
-            wal_bytes += 1 << 16   # spill-map double buffer + head regions
-        self.wal_pool = Pool.open_or_create(wal_path, wal_bytes)
-        self.wal_pmem = self.wal_pool.pmem
-        self.wal = self.wal_pool.wal(
-            "train_wal", capacity_steps=tc.wal_capacity_steps,
-            lanes=tc.wal_lanes, group_commit=tc.wal_group_commit,
-            gen_sets=tc.wal_gen_sets)
-        self.wal_spill = None
-        if self.wal.generational:
-            # the ring needs a retirement path: sealed step generations
-            # move to SSD at the checkpoint cadence (the durable retired
-            # watermark keeps every generation recoverable from exactly
-            # one tier), bounding the WAL's PMem footprint for good
-            from repro.core.ssd import SSD
-            from repro.tier import SpillScheduler
-            self.wal_pool.attach_ssd(SSD(1 << 26))
-            self.wal_spill = SpillScheduler(self.wal_pool, name="twsp",
-                                            map_capacity=1 << 14)
-            self.wal.log.attach_spill(self.wal_spill)
+        with span("trainer.wal_open"):
+            wal_path = os.path.join(tc.out, "wal.pmem")
+            wal_bytes = TrainWAL.capacity_for(tc.wal_capacity_steps,
+                                              lanes=tc.wal_lanes,
+                                              gen_sets=tc.wal_gen_sets)
+            if tc.wal_gen_sets > 1:
+                wal_bytes += 1 << 16   # spill-map double buffer + head regions
+            self.wal_pool = Pool.open_or_create(wal_path, wal_bytes)
+            self.wal_pmem = self.wal_pool.pmem
+            self.wal = self.wal_pool.wal(
+                "train_wal", capacity_steps=tc.wal_capacity_steps,
+                lanes=tc.wal_lanes, group_commit=tc.wal_group_commit,
+                gen_sets=tc.wal_gen_sets)
+            self.wal_spill = None
+            if self.wal.generational:
+                # the ring needs a retirement path: sealed step generations
+                # move to SSD at the checkpoint cadence (the durable retired
+                # watermark keeps every generation recoverable from exactly
+                # one tier), bounding the WAL's PMem footprint for good
+                from repro.core.ssd import SSD
+                from repro.tier import SpillScheduler
+                self.wal_pool.attach_ssd(SSD(1 << 26))
+                self.wal_spill = SpillScheduler(self.wal_pool, name="twsp",
+                                                map_capacity=1 << 14)
+                self.wal.log.attach_spill(self.wal_spill)
         self.manager = CheckpointManager(
             os.path.join(tc.out, "ckpt.pmem"),
             CheckpointConfig(page_size=128 * 1024))
@@ -147,8 +159,10 @@ class Trainer:
             tmpl_o = jax.eval_shape(adamw_init, tmpl_p)
             np_params = {k[2:]: v for k, v in flat.items() if k.startswith("p/")}
             np_opt = {k[2:]: v for k, v in flat.items() if k.startswith("o/")}
-            params = unflatten_like(tmpl_p, np_params)
-            opt_state = unflatten_like(tmpl_o, np_opt)
+            with span("trainer.upload") as sp:
+                params = unflatten_like(tmpl_p, np_params)
+                opt_state = unflatten_like(tmpl_o, np_opt)
+                sp.add(h2d_bytes=sum(v.nbytes for v in flat.values()))
             self.start_step = step
             print(f"[train] restored checkpoint @ step {step}")
             if self.wal.last is not None and self.wal.last.step > step:
@@ -161,8 +175,12 @@ class Trainer:
         self.params, self.opt_state = params, opt_state
 
     def _ckpt_state(self) -> Dict[str, np.ndarray]:
-        flat = {f"p/{k}": v for k, v in flatten_state(self.params).items()}
-        flat.update({f"o/{k}": v for k, v in flatten_state(self.opt_state).items()})
+        """The state to checkpoint, fetched to the host (``ckpt.stage``)."""
+        with span("ckpt.stage") as sp:
+            flat = {f"p/{k}": v for k, v in flatten_state(self.params).items()}
+            flat.update({f"o/{k}": v
+                         for k, v in flatten_state(self.opt_state).items()})
+            sp.add(d2h_bytes=sum(v.nbytes for v in flat.values()))
         return flat
 
     def run(self, crash_at: Optional[int] = None) -> Dict[str, Any]:
@@ -173,19 +191,23 @@ class Trainer:
             if crash_at is not None and step == crash_at:
                 # simulated process death: no cleanup, no final flush
                 return {"crashed_at": step, "losses": losses}
-            batch = {k: jnp.asarray(v)
-                     for k, v in self.pipeline.batch_at(step).items()}
-            self.params, self.opt_state, metrics = self.step_fn(
-                self.params, self.opt_state, batch)
-            loss = float(metrics["loss"])
+            with span("train.step", step=step) as sp:
+                before = compiles()
+                batch = {k: jnp.asarray(v)
+                         for k, v in self.pipeline.batch_at(step).items()}
+                self.params, self.opt_state, metrics = self.step_fn(
+                    self.params, self.opt_state, batch)
+                loss = float(metrics["loss"])
+                sp.add(compiles=compiles() - before)
             losses.append(loss)
             # WAL commit: ONE barrier on the critical path (Zero logging);
             # with group commit enabled, steps buffer and the barrier is
             # amortized per batch (crash loses at most a replayable tail)
-            self.wal.commit_step(StepRecord(
-                step + 1, step + 1, (0, 0), loss,
-                float(metrics["grad_norm"]), 1.0, time.time_ns()),
-                sync=tc.wal_group_commit <= 1)
+            with span("train.wal_commit"):
+                self.wal.commit_step(StepRecord(
+                    step + 1, step + 1, (0, 0), loss,
+                    float(metrics["grad_norm"]), 1.0, time.time_ns()),
+                    sync=tc.wal_group_commit <= 1)
             if (step + 1) % tc.ckpt_every == 0:
                 state = self._ckpt_state()
                 if self.flusher is not None:

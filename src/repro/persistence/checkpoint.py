@@ -58,6 +58,7 @@ from repro.core.pageflush import HybridPolicy, PageStore, PageStoreLayout
 from repro.core.persist import AccessPattern, FlushKind
 from repro.core.pmem import PMem, PMemStats
 from repro.pool import LogHandle, PagesHandle, Pool
+from repro.spans import span
 from repro.kernels.apply_unpack import apply_unpack
 from repro.kernels.common import resolve_impl
 from repro.kernels.dirty_diff import dirty_blocks
@@ -69,6 +70,10 @@ __all__ = ["CheckpointConfig", "CheckpointManager", "RestoreReport",
 
 #: checkpoint geometry: dirty unit = 4 KiB TPU tile, write granule = 16 KiB
 CKPT_GEOMETRY = BlockGeometry(cache_line=TPU_TILE, block=4 * TPU_TILE)
+
+#: bytes of the one int32 that ``int()`` fetches from the device (a
+#: kernel's dirty-block total, a restore's failed-verdict count)
+_SCALAR_BYTES = 4
 
 #: spill-map log capacity per buffer for a tiered shard — 4 KiB lines pad
 #: each map record to a line, so the maps need real capacity; referenced by
@@ -139,6 +144,12 @@ class CheckpointConfig:
 
 @dataclasses.dataclass
 class SaveReport:
+    """What one :meth:`CheckpointManager.save` did. ``phase_s``,
+    ``wall_s``, ``h2d_bytes`` and ``d2h_bytes`` are measured on the
+    host's clock and counted where the code moves the bytes (the
+    ``repro:ckpt.save*`` spans, :mod:`repro.spans`); the ``*_ns`` fields
+    are the cost model's outputs for the simulated PMem, never measured."""
+
     step: int
     pages_total: int = 0
     pages_cow: int = 0
@@ -162,6 +173,15 @@ class SaveReport:
     #: what ran the scan: "pallas" (compiled), "interpret", "ref" or
     #: "staged" (see :attr:`CheckpointManager.scan_impl`)
     kernel_impl: str = ""
+    #: measured self seconds of each phase: ``ckpt.save.snapshot``,
+    #: ``.scan``, ``.build`` (summed over the leaves), ``.epoch``, ``.commit``
+    phase_s: Dict[str, float] = dataclasses.field(default_factory=dict)
+    #: measured seconds of the whole save
+    wall_s: float = 0.0
+    #: bytes uploaded to the device (live leaves and their snapshots) and
+    #: fetched from it (checksums, dirty block ids) by the save's scans
+    h2d_bytes: int = 0
+    d2h_bytes: int = 0
 
     @property
     def bytes_device(self) -> int:
@@ -176,7 +196,9 @@ class RestoreReport:
     pass over the packed page images with the fused ``apply_unpack``
     kernel, two (verify + copy) when staged. ``scan_ns`` prices that
     traffic alone; ``modeled_ns`` folds it into the pool's full delta
-    via ``engine_time_ns(scan_read_bytes=)``."""
+    via ``engine_time_ns(scan_read_bytes=)``. ``phase_s``, ``wall_s``,
+    ``h2d_bytes`` and ``d2h_bytes`` are measured on the host's clock, as
+    in :class:`SaveReport`; the ``*_ns`` fields are modeled."""
 
     step: int = -1
     #: manifest entries walked (newest-first) before one verified
@@ -189,6 +211,14 @@ class RestoreReport:
     modeled_ns: float = 0.0
     #: what verified and assembled the pages, as in :class:`SaveReport`
     kernel_impl: str = ""
+    #: measured self seconds of ``ckpt.restore.open``, ``.scan`` (summed
+    #: over the leaves of every entry tried) and ``.adopt``
+    phase_s: Dict[str, float] = dataclasses.field(default_factory=dict)
+    wall_s: float = 0.0
+    #: bytes the restore scans uploaded (packed pages, zero base, block
+    #: ids, checksums) and fetched (the assembled images, verdicts)
+    h2d_bytes: int = 0
+    d2h_bytes: int = 0
 
 
 class CheckpointManager:
@@ -234,6 +264,10 @@ class CheckpointManager:
         self.last_restore: Optional[RestoreReport] = None
         self._restore_read_bytes = 0
         self._restore_pages_spilled = 0
+        #: phase seconds and host<->device bytes of the save or restore
+        #: in progress (its report's ``phase_s`` while one runs)
+        self._phase_s: Dict[str, float] = {}
+        self._h2d = self._d2h = 0
 
     # ----------------------------------------------------------- layout
 
@@ -360,20 +394,41 @@ class CheckpointManager:
         the kernel's on-device prefix-sum compaction — no host-side
         ``flatnonzero`` over the flag vector. ``kernel_impl="staged"``
         runs the pre-fusion chain instead (dirty_diff + popcnt + the
-        shared compaction), reading the live buffer thrice."""
+        shared compaction), reading the live buffer thrice.
+
+        Two phase spans per leaf: ``ckpt.save.snapshot`` (the snapshot
+        from the cache frames) and ``ckpt.save.scan`` (uploads, the scan,
+        and the fetch of its outputs, where the host first waits)."""
+        with span("ckpt.save.snapshot", into=self._phase_s):
+            snap = self._leaf_snapshot(name)
+        with span("ckpt.save.scan", into=self._phase_s) as sp:
+            h2d, d2h = self._h2d, self._d2h
+            per_page, buf, counts, dirty = self._scan_leaf(cur, snap)
+            sp.add(h2d_bytes=self._h2d - h2d, d2h_bytes=self._d2h - d2h,
+                   blocks_dirty=dirty)
+        return per_page, buf, counts
+
+    def _scan_leaf(self, cur: jax.Array | np.ndarray,
+                   snap: Optional[np.ndarray]):
+        """:meth:`_dirty_lines_per_page`'s device pass → (per_page, buf,
+        counts, dirty block count), counting the bytes it moves."""
+        if isinstance(cur, jax.Array):
+            self._d2h += cur.nbytes
         buf = self._leaf_bytes(cur)
-        snap = self._leaf_snapshot(name)
         cl = self.cfg.geometry.cache_line
         impl = self.scan_impl
         jbuf = jax.numpy.asarray(buf)
+        self._h2d += buf.size
         if snap is None or not self.cfg.delta:
             # the staged chain's popcount pass dispatches like its others
             counts = np.asarray(popcount_blocks(
                 jbuf, block_bytes=cl,
                 impl="auto" if impl == "staged" else impl))
+            self._d2h += counts.nbytes
             self._note_scan(buf.size)   # full rewrite: one pass, no diff
-            return None, buf, counts
+            return None, buf, counts, counts.size
         jsnap = jax.numpy.asarray(snap)
+        self._h2d += snap.size
         if impl == "staged":
             flags = dirty_blocks(jbuf, jsnap, block_bytes=cl)
             counts = np.asarray(popcount_blocks(jbuf, block_bytes=cl))
@@ -388,11 +443,12 @@ class CheckpointManager:
             dirty_idx = np.asarray(fp.index[: fp.total])
             counts = np.asarray(fp.counts)
             self._note_scan(buf.size)   # the whole point: one pass
+        self._d2h += _SCALAR_BYTES + dirty_idx.nbytes + counts.nbytes
         per_page: Dict[int, set] = {}
         lpp = self.cfg.blocks_per_page
         for b in dirty_idx.tolist():
             per_page.setdefault(b // lpp, set()).add(b % lpp)
-        return per_page, buf, counts
+        return per_page, buf, counts, dirty_idx.size
 
     def _leaf_snapshot(self, name: str) -> Optional[np.ndarray]:
         """Last-flushed bytes of a leaf, reassembled from the buffer
@@ -412,6 +468,19 @@ class CheckpointManager:
         return out[: self._leaf_meta[name]["nbytes"]]
 
     def save(self, step: int, state: Dict[str, Any]) -> SaveReport:
+        """Save ``state`` as checkpoint ``step``; durable on return. The
+        whole save is the ``ckpt.save`` span, its five phases spans of
+        their own (see :class:`SaveReport`)."""
+        report = SaveReport(step=step, kernel_impl=self.scan_impl)
+        with span("ckpt.save", step=step, shard=self.shard_id) as sp:
+            self._save(state, report)
+            sp.add(h2d_bytes=report.h2d_bytes, d2h_bytes=report.d2h_bytes,
+                   pages_dirty=report.pages_total - report.pages_clean,
+                   leaves=len(state))
+        report.wall_s = sp.seconds
+        return report
+
+    def _save(self, state: Dict[str, Any], report: SaveReport) -> None:
         if self.pmem is None:
             self._build(state)
         assert self.store is not None and self.manifest is not None
@@ -419,8 +488,10 @@ class CheckpointManager:
             raise ValueError("state keys changed between saves")
         cfg = self.cfg
         before: PMemStats = self.pmem.stats.snapshot()
-        report = SaveReport(step=step, kernel_impl=self.scan_impl)
-        entry: Dict[str, Any] = {"step": step, "shard": self.shard_id, "leaves": {}}
+        entry: Dict[str, Any] = {"step": report.step, "shard": self.shard_id,
+                                 "leaves": {}}
+        self._phase_s = report.phase_s
+        self._h2d = self._d2h = 0
 
         # Pass 1 — dirty scan + page build: clean pages keep their slot,
         # dirty pages are enqueued on the engine's flush queue.
@@ -429,30 +500,10 @@ class CheckpointManager:
         leaf_checks: Dict[str, List[int]] = {}
         for name in sorted(state):
             per_page, buf, counts = self._dirty_lines_per_page(name, state[name])
-            report.bytes_logical += buf.size
-            pages = self._leaf_pages[name]
-            lpp = cfg.blocks_per_page
-            checks = []
-            for i, pid in enumerate(pages):
-                lo = i * cfg.page_size
-                page = np.zeros(cfg.page_size, dtype=np.uint8)
-                chunk = buf[lo : lo + cfg.page_size]
-                page[: chunk.size] = chunk
-                report.pages_total += 1
-                # page checksum from the fused scan's per-block popcounts
-                # (zero padding beyond the leaf contributes 0 bits)
-                blk = counts[i * lpp : (i + 1) * lpp]
-                checks.append(int((int(blk.sum(dtype=np.uint64)) + 1) & 0xFFFFFFFF))
-                if per_page is None:
-                    # first save / no delta: full rewrite, forced CoW
-                    self._cache.put(pid, page, None, store=self.store)
-                    continue
-                dirty = per_page.get(i, set())
-                if not dirty:
-                    report.pages_clean += 1   # previous version still valid
-                    continue
-                self._cache.put(pid, page, sorted(dirty), store=self.store)
-            leaf_checks[name] = checks
+            with span("ckpt.save.build", into=report.phase_s):
+                leaf_checks[name] = self._build_pages(name, per_page, buf,
+                                                      counts, report)
+        report.h2d_bytes, report.d2h_bytes = self._h2d, self._d2h
 
         # Pass 2 — the buffer manager's write-back: one lane-partitioned
         # epoch drains every dirty frame (pinned for the duration); the
@@ -460,7 +511,8 @@ class CheckpointManager:
         # count, not the constructor's thread constant. The frames stay
         # resident holding exactly the flushed bytes — the next save's
         # dirty-diff snapshots.
-        epoch = self._cache.writeback(self.store)
+        with span("ckpt.save.epoch", into=report.phase_s):
+            epoch = self._cache.writeback(self.store)
         report.active_lanes = max(1, epoch.active_lanes)
         report.pages_spilled = epoch.pages_spilled
         report.spill_ns = epoch.spill_ns
@@ -468,20 +520,23 @@ class CheckpointManager:
         report.scan_ns = epoch.scan_ns
         self._prev_dirty.update(self._epoch_prev_dirty)
 
-        # Pass 3 — manifest records from the post-epoch page table. A
-        # page whose slot spilled during the epoch is recorded with
-        # slot -1 and its SSD-resident pvn: restore reads it back through
-        # the spill map (same checksum verification, different tier).
-        for name in sorted(state):
-            page_records = [self._page_record(pid)
-                            for pid in self._leaf_pages[name]]
-            entry["leaves"][name] = dict(
-                self._leaf_meta[name], pages=page_records,
-                checksums=leaf_checks[name])
+        with span("ckpt.save.commit", into=report.phase_s):
+            # Pass 3 — manifest records from the post-epoch page table. A
+            # page whose slot spilled during the epoch is recorded with
+            # slot -1 and its SSD-resident pvn: restore reads it back
+            # through the spill map (same checksum verification, other
+            # tier).
+            for name in sorted(state):
+                page_records = [self._page_record(pid)
+                                for pid in self._leaf_pages[name]]
+                entry["leaves"][name] = dict(
+                    self._leaf_meta[name], pages=page_records,
+                    checksums=leaf_checks[name])
 
-        # commit: one Zero-log barrier makes the whole checkpoint durable
-        self.manifest.append(json.dumps(entry).encode())
-        self.pmem.fsync()
+            # commit: one Zero-log barrier makes the whole checkpoint
+            # durable
+            self.manifest.append(json.dumps(entry).encode())
+            self.pmem.fsync()
         self._saves += 1
         delta = self.pmem.stats.delta(before)
         report.barriers = delta.barriers
@@ -490,7 +545,36 @@ class CheckpointManager:
             delta, active_lanes=report.active_lanes, kind=FlushKind.NT,
             pattern=AccessPattern.SEQUENTIAL, burst=True,
             scan_read_bytes=report.scan_read_bytes)
-        return report
+
+    def _build_pages(self, name: str, per_page: Optional[Dict[int, set]],
+                     buf: np.ndarray, counts: np.ndarray,
+                     report: SaveReport) -> List[int]:
+        """One leaf's pages into the buffer manager (dirty ones only,
+        after a delta scan) → the leaf's page checksums."""
+        cfg = self.cfg
+        lpp = cfg.blocks_per_page
+        checks = []
+        for i, pid in enumerate(self._leaf_pages[name]):
+            lo = i * cfg.page_size
+            page = np.zeros(cfg.page_size, dtype=np.uint8)
+            chunk = buf[lo : lo + cfg.page_size]
+            page[: chunk.size] = chunk
+            report.pages_total += 1
+            # page checksum from the fused scan's per-block popcounts
+            # (zero padding beyond the leaf contributes 0 bits)
+            blk = counts[i * lpp : (i + 1) * lpp]
+            checks.append(int((int(blk.sum(dtype=np.uint64)) + 1) & 0xFFFFFFFF))
+            if per_page is None:
+                # first save / no delta: full rewrite, forced CoW
+                self._cache.put(pid, page, None, store=self.store)
+                continue
+            dirty = per_page.get(i, set())
+            if not dirty:
+                report.pages_clean += 1   # previous version still valid
+                continue
+            self._cache.put(pid, page, sorted(dirty), store=self.store)
+        report.bytes_logical += buf.size
+        return checks
 
     def _page_record(self, pid: int) -> List[int]:
         """Manifest record for one page: ``[pid, slot, pvn]`` when PMem-
@@ -564,37 +648,54 @@ class CheckpointManager:
         of the save scan's ``flush_pack``); ``cfg.kernel_impl="staged"``
         keeps the pre-fusion verify-then-copy chain, which reads the
         restored bytes twice. Either way the read traffic and modeled
-        time land in :attr:`last_restore` (a :class:`RestoreReport`)."""
-        path = path or self.path
-        cfg = self.cfg
-        if self.pool is None:
-            if path is None:
-                raise ValueError("nothing to restore from")
-            if not os.path.exists(path):
-                raise FileNotFoundError(path)
-            self.pool = Pool.open(path)
-            self.pmem = self.pool.pmem
-            self.manifest = self.pool.log("manifest")
-        if cfg.pmem_slot_budget is not None and self._spill is None:
-            from repro.tier import SpillScheduler
-            if self._ssd is None:
-                raise ValueError(
-                    "this shard was saved with a PMem slot budget — its "
-                    "cold pages live on SSD; pass the shard's SSD device "
-                    "to CheckpointManager(ssd=...) before restoring")
-            self.pool.attach_ssd(self._ssd)
-            self._spill = SpillScheduler(self.pool, name="sp",
-                                         map_capacity=_SPILL_MAP_CAPACITY)
-        rec = self.manifest.recover()
-        if not rec.entries:
-            raise FileNotFoundError("no committed checkpoint manifest")
-        # layout from the durable directory record — deliberately without
-        # opening the page store (that would replay µlogs before the
-        # manifests are verified against the untouched image)
-        self._layout = self.pool.pages_layout("pages")
-        img = self.pmem.durable_view()
-        before: PMemStats = self.pmem.stats.snapshot()
+        time land in :attr:`last_restore` (a :class:`RestoreReport`).
+
+        The whole restore is the ``ckpt.restore`` span; its phases are
+        ``ckpt.restore.open``, ``.scan`` (one per leaf) and ``.adopt``."""
         report = RestoreReport(kernel_impl=self.scan_impl)
+        with span("ckpt.restore") as sp:
+            try:
+                restored = self._restore(path or self.path, verify, report)
+            finally:
+                sp.add(step=report.step, entries_tried=report.entries_tried,
+                       h2d_bytes=self._h2d, d2h_bytes=self._d2h)
+        report.h2d_bytes, report.d2h_bytes = self._h2d, self._d2h
+        report.wall_s = sp.seconds
+        return restored
+
+    def _restore(self, path: Optional[str], verify: bool,
+                 report: RestoreReport) -> Tuple[int, Dict[str, np.ndarray]]:
+        cfg = self.cfg
+        self._phase_s = report.phase_s
+        self._h2d = self._d2h = 0
+        with span("ckpt.restore.open", into=report.phase_s):
+            if self.pool is None:
+                if path is None:
+                    raise ValueError("nothing to restore from")
+                if not os.path.exists(path):
+                    raise FileNotFoundError(path)
+                self.pool = Pool.open(path)
+                self.pmem = self.pool.pmem
+                self.manifest = self.pool.log("manifest")
+            if cfg.pmem_slot_budget is not None and self._spill is None:
+                from repro.tier import SpillScheduler
+                if self._ssd is None:
+                    raise ValueError(
+                        "this shard was saved with a PMem slot budget — its "
+                        "cold pages live on SSD; pass the shard's SSD device "
+                        "to CheckpointManager(ssd=...) before restoring")
+                self.pool.attach_ssd(self._ssd)
+                self._spill = SpillScheduler(self.pool, name="sp",
+                                             map_capacity=_SPILL_MAP_CAPACITY)
+            rec = self.manifest.recover()
+            if not rec.entries:
+                raise FileNotFoundError("no committed checkpoint manifest")
+            # layout from the durable directory record — deliberately without
+            # opening the page store (that would replay µlogs before the
+            # manifests are verified against the untouched image)
+            self._layout = self.pool.pages_layout("pages")
+            img = self.pmem.durable_view()
+        before: PMemStats = self.pmem.stats.snapshot()
         self._restore_read_bytes = 0
         self._restore_pages_spilled = 0
         for raw in reversed(rec.entries):
@@ -602,7 +703,8 @@ class CheckpointManager:
             report.entries_tried += 1
             state = self._try_restore_entry(entry, img, verify)
             if state is not None:
-                self._adopt(entry, state)
+                with span("ckpt.restore.adopt", into=report.phase_s):
+                    self._adopt(entry, state)
                 report.step = entry["step"]
                 report.pages_total = sum(
                     len(meta["pages"]) for meta in entry["leaves"].values())
@@ -624,49 +726,61 @@ class CheckpointManager:
         verifies. The slot-header checks are host-side (a 12-byte unpack
         per page); the data work — checksum verification + image
         assembly — is one fused ``apply_unpack`` pass per leaf, or the
-        staged verify-then-copy chain under ``kernel_impl="staged"``."""
+        staged verify-then-copy chain under ``kernel_impl="staged"``.
+        Each leaf is one ``ckpt.restore.scan`` span."""
+        state: Dict[str, np.ndarray] = {}
+        for name, meta in entry["leaves"].items():
+            with span("ckpt.restore.scan", into=self._phase_s) as sp:
+                h2d, d2h = self._h2d, self._d2h
+                arr = self._restore_leaf(meta, img, verify)
+                sp.add(h2d_bytes=self._h2d - h2d, d2h_bytes=self._d2h - d2h)
+            if arr is None:
+                return None
+            state[name] = arr
+        return state
+
+    def _restore_leaf(self, meta: Dict[str, Any], img: np.ndarray,
+                      verify: bool) -> Optional[np.ndarray]:
+        """One leaf of a manifest entry → its array, or None if a page's
+        slot was reused or a checksum fails."""
         import struct as _s
         cfg = self.cfg
-        state: Dict[str, np.ndarray] = {}
         layout = self._layout
-        staged = self.scan_impl == "staged"
-        for name, meta in entry["leaves"].items():
-            pages: List[Optional[np.ndarray]] = []
-            spilled: List[Tuple[int, int, int]] = []   # (pos, pid, pvn)
-            for i, (pid, slot, pvn) in enumerate(meta["pages"]):
-                if slot == -1:
-                    # SSD-resident page: the manifest pinned its pvn; the
-                    # spill map must still hold exactly that version
-                    if self._spill is None:
-                        return None
-                    spilled.append((i, pid, pvn))
-                    pages.append(None)
-                    continue
-                hdr_pid, hdr_pvn = _s.unpack_from("<IQ", img,
-                                                  layout.slot_off(slot))
-                if hdr_pid != pid or hdr_pvn != pvn:
-                    return None   # slot was reused; not restorable
-                off = layout.slot_data_off(slot)
-                pages.append(img[off : off + cfg.page_size])
-            if spilled:
-                try:
-                    got = self._spill.read_spilled_many(
-                        "pages", [(pid, pvn) for _, pid, pvn in spilled])
-                except (KeyError, RuntimeError):
+        pages: List[Optional[np.ndarray]] = []
+        spilled: List[Tuple[int, int, int]] = []   # (pos, pid, pvn)
+        for i, (pid, slot, pvn) in enumerate(meta["pages"]):
+            if slot == -1:
+                # SSD-resident page: the manifest pinned its pvn; the
+                # spill map must still hold exactly that version
+                if self._spill is None:
                     return None
-                for (pos, _, _), page in zip(spilled, got):
-                    pages[pos] = page
-                self._restore_pages_spilled += len(spilled)
-            csums = meta["checksums"]
-            if staged:
-                buf = self._staged_assemble(pages, csums, verify)
-            else:
-                buf = self._fused_assemble(pages, csums, verify)
-            if buf is None:
+                spilled.append((i, pid, pvn))
+                pages.append(None)
+                continue
+            hdr_pid, hdr_pvn = _s.unpack_from("<IQ", img,
+                                              layout.slot_off(slot))
+            if hdr_pid != pid or hdr_pvn != pvn:
+                return None   # slot was reused; not restorable
+            off = layout.slot_data_off(slot)
+            pages.append(img[off : off + cfg.page_size])
+        if spilled:
+            try:
+                got = self._spill.read_spilled_many(
+                    "pages", [(pid, pvn) for _, pid, pvn in spilled])
+            except (KeyError, RuntimeError):
                 return None
-            arr = buf[: meta["nbytes"]].view(np.dtype(meta["dtype"]))
-            state[name] = arr.reshape(meta["shape"])
-        return state
+            for (pos, _, _), page in zip(spilled, got):
+                pages[pos] = page
+            self._restore_pages_spilled += len(spilled)
+        csums = meta["checksums"]
+        if self.scan_impl == "staged":
+            buf = self._staged_assemble(pages, csums, verify)
+        else:
+            buf = self._fused_assemble(pages, csums, verify)
+        if buf is None:
+            return None
+        arr = buf[: meta["nbytes"]].view(np.dtype(meta["dtype"]))
+        return arr.reshape(meta["shape"])
 
     def _staged_assemble(self, pages: Sequence[np.ndarray],
                          csums: Sequence[int],
@@ -699,16 +813,23 @@ class CheckpointManager:
         # manifest stores popcount+1 (the Zero-log cnt==0 convention)
         expected = ((np.asarray(csums, dtype=np.int64) - 1)
                     & 0xFFFFFFFF).astype(np.uint32)
-        res = apply_unpack(base, packed,
-                           np.arange(k, dtype=np.int32), expected,
+        index = np.arange(k, dtype=np.int32)
+        res = apply_unpack(base, packed, index, expected,
                            block_bytes=cfg.page_size,
                            impl=self.scan_impl)
         self._restore_read_bytes += k * cfg.page_size   # one pass, fused
+        self._h2d += base.nbytes + packed.nbytes + index.nbytes \
+            + expected.nbytes
+        self._d2h += _SCALAR_BYTES                      # res.nbad
         if verify and res.nbad:
+            ok = np.asarray(res.ok)
+            self._d2h += ok.nbytes
             skip = np.asarray(csums, dtype=np.uint32) == 0
-            if np.any((np.asarray(res.ok) == 0) & ~skip):
+            if np.any((ok == 0) & ~skip):
                 return None
-        return np.asarray(res.out)
+        out = np.asarray(res.out)
+        self._d2h += out.nbytes
+        return out
 
     def _adopt(self, entry: Dict[str, Any], state: Dict[str, np.ndarray]) -> None:
         """Rebuild volatile metadata so saving can continue after restore."""

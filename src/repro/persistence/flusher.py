@@ -39,6 +39,7 @@ from repro.persistence.checkpoint import (
     CheckpointManager,
     SaveReport,
 )
+from repro.spans import span
 
 __all__ = ["AsyncFlusher"]
 
@@ -159,14 +160,21 @@ class AsyncFlusher:
     def stage(state: Dict[str, Any]) -> Dict[str, np.ndarray]:
         """Device→host staging copy (the only synchronous cost). Must be a
         real copy: the training loop mutates the live buffers immediately
-        after submit()."""
-        return {k: np.array(v, copy=True) for k, v in state.items()}
+        after submit(). The ``flusher.stage`` span counts its ``bytes``."""
+        with span("flusher.stage") as sp:
+            staged = {k: np.array(v, copy=True) for k, v in state.items()}
+            sp.add(bytes=sum(v.nbytes for v in staged.values()))
+        return staged
 
     def submit(self, step: int, state: Dict[str, Any], *, shard: int = 0) -> None:
         """Stage and enqueue one shard's save; blocks only if that shard
         already has ``max_pending`` saves in flight (back-pressure instead
-        of unbounded host RAM)."""
-        self._queues[shard].put((step, self.stage(state)))
+        of unbounded host RAM). The wait for a slot is the
+        ``flusher.queue_wait`` span, with the queue's ``depth`` at entry."""
+        staged = self.stage(state)
+        q = self._queues[shard]
+        with span("flusher.queue_wait", depth=q.qsize(), shard=shard):
+            q.put((step, staged))
 
     def submit_all(self, step: int, states: Sequence[Dict[str, Any]]) -> None:
         """Stage and enqueue one save per shard (lane-parallel flush)."""
