@@ -251,7 +251,7 @@ class ClassicLog(_LogBase):
     def recover(cls, pmem: PMem, base: int, capacity: int,
                 cfg: Optional[LogConfig] = None) -> RecoveredLog:
         cfg = cfg or LogConfig()
-        img = pmem.durable_view()[base : base + capacity]
+        img = pmem.durable_inplace(base, capacity)
         entries: List[bytes] = []
         lsns: List[int] = []
         offsets: List[int] = []
@@ -367,7 +367,7 @@ class HeaderLog(_LogBase):
     def recover(cls, pmem: PMem, base: int, capacity: int,
                 cfg: Optional[LogConfig] = None) -> RecoveredLog:
         cfg = cfg or LogConfig()
-        img = pmem.durable_view()[base : base + capacity]
+        img = pmem.durable_inplace(base, capacity)
         data_start = align_up(cfg.dancing * cfg.geometry.cache_line, cfg.geometry.block)
         size = 0
         for slot in range(cfg.dancing):
@@ -454,7 +454,7 @@ class ZeroLog(_LogBase):
     def recover(cls, pmem: PMem, base: int, capacity: int,
                 cfg: Optional[LogConfig] = None) -> RecoveredLog:
         cfg = cfg or LogConfig()
-        img = pmem.durable_view()[base : base + capacity]
+        img = pmem.durable_inplace(base, capacity)
         entries: List[bytes] = []
         lsns: List[int] = []
         offsets: List[int] = []

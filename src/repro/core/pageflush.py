@@ -103,7 +103,7 @@ class PageStoreLayout:
 def recover_page_table(pmem: PMem, layout: PageStoreLayout) -> Dict[int, Tuple[int, int]]:
     """Scan all slot headers in the durable image; return pid -> (slot, pvn)
     picking the highest pvn per pid (paper §3.2.1 recovery)."""
-    img = pmem.durable_view()
+    img = pmem.durable_inplace()
     table: Dict[int, Tuple[int, int]] = {}
     for s in range(layout.nslots):
         pid, pvn = _SLOT_HDR.unpack_from(img, layout.slot_off(s))
@@ -157,7 +157,7 @@ class MicroLog:
 
     def read_durable(self) -> Optional[Tuple[int, int, int, np.ndarray, np.ndarray]]:
         """(pid, pvn, slot, line_idx[n], line_data[n, cl]) if durably valid."""
-        img = self.pmem.durable_view()
+        img = self.pmem.durable_inplace()
         pid, pvn, slot, nlines = _ULOG_HDR.unpack_from(img, self.base)
         if pid == INVALID_PID or pid >= self.layout.npages or nlines == 0:
             return None
@@ -258,7 +258,7 @@ class PageStore:
                 # replays: hdr_pid matches and hdr_pvn <= pvn.
                 slot = target
                 hdr_pid, hdr_pvn = _SLOT_HDR.unpack_from(
-                    pmem.durable_view(), layout.slot_off(target))
+                    pmem.durable_inplace(), layout.slot_off(target))
                 if hdr_pid != pid or hdr_pvn > pvn:
                     continue  # slot reused / superseded: µlog is stale
             elif pvn < slot_pvn:
@@ -446,9 +446,8 @@ class PageStore:
         if pid not in table:
             return None
         slot, _ = table[pid]
-        img = self.pmem.durable_view()
-        off = self.layout.slot_data_off(slot)
-        return img[off : off + self.layout.page_size]
+        return self.pmem.durable_slice(self.layout.slot_data_off(slot),
+                                       self.layout.page_size)
 
 
 class HybridPolicy:

@@ -22,7 +22,10 @@ on. Two concerns are deliberately separated:
 
 The region is optionally file-backed (``np.memmap``) so the training
 checkpoint/WAL layer gets real on-disk persistence; crash simulation then
-operates on the in-memory cache layers only.
+operates on the in-memory cache layers only. A file-backed region reads
+nothing up front: the program-visible image is a private copy-on-write
+mapping of the same file, and recovery reads the durable image in place
+(:meth:`PMem.durable_inplace`).
 """
 
 from __future__ import annotations
@@ -152,8 +155,13 @@ class PMem:
         else:
             self._durable = np.zeros(self.size, dtype=np.uint8)
         self.path = path
+        #: bytes copied out of the durable image (``durable_view``,
+        #: ``durable_slice``, eager copies into the logical image): host
+        #: bookkeeping, deliberately outside :class:`PMemStats`, so no
+        #: modelled count depends on how the host reads the image.
+        self.durable_copy_bytes = 0
         # Program-visible contents (cache + durable merged).
-        self._logical = np.array(self._durable, dtype=np.uint8, copy=True)
+        self._logical = self._fresh_logical()
         # Dirty cache lines: line index -> None (data lives in _logical).
         self._dirty: Set[int] = set()
         # Lines flushed (clwb/clflush/clflushopt) but not yet fenced. The
@@ -180,6 +188,23 @@ class PMem:
         self._home_ends: list = []
         self._home_sockets: list = []
         self.stats = PMemStats()
+
+    def _fresh_logical(self) -> np.ndarray:
+        """A program-visible image equal to the durable one.
+
+        File-backed: a private copy-on-write mapping of the region's file.
+        Pages never stored to read the file's current bytes; the first
+        store to a page gives it a private copy. Every byte
+        :meth:`_commit` writes to the durable image comes from this one
+        (directly, or through ``_staged``/``_wc``), so a page never stored
+        to is the same in both, and nothing is read at open. In memory:
+        an eager copy."""
+        if self.path is not None:
+            cow = np.memmap(self.path, dtype=np.uint8, mode="c",
+                            shape=(self.size,))
+            return cow.view(np.ndarray)
+        self.durable_copy_bytes += self.size
+        return np.array(self._durable, dtype=np.uint8, copy=True)
 
     # ----------------------------------------------------------------- lanes
 
@@ -432,9 +457,9 @@ class PMem:
         self._staged.clear()
         self._wc.clear()
         self._clean.clear()
-        self._logical = np.array(self._durable, dtype=np.uint8, copy=True)
+        self._logical = self._fresh_logical()
         return CrashImage(
-            durable=np.array(self._durable, copy=True),
+            durable=self.durable_view(),
             evicted_lines=evicted,
             dropped_lines=dropped,
         )
@@ -442,7 +467,9 @@ class PMem:
     # ---------------------------------------------------------------- misc
 
     def durable_view(self) -> np.ndarray:
-        """The current durable image (what recovery would see)."""
+        """A snapshot of the current durable image (what recovery would
+        see): an independent copy that later writes leave as it is."""
+        self.durable_copy_bytes += self.size
         return np.array(self._durable, copy=True)
 
     def durable_slice(self, off: int, size: int) -> np.ndarray:
@@ -450,7 +477,22 @@ class PMem:
         small structures (roots, directory tables) without paying an
         O(region) copy."""
         self._check(off, size)
+        self.durable_copy_bytes += size
         return np.array(self._durable[off : off + size], copy=True)
+
+    def durable_inplace(self, off: int = 0,
+                        size: Optional[int] = None) -> np.ndarray:
+        """Read-only view of the durable image's bytes ``[off, off+size)``
+        (to the region's end by default) where they lie — no copy. For
+        readers that read a range and drop it, such as recovery: the view
+        aliases the image, so a later commit shows through it, and a
+        write into it raises. :meth:`durable_view` is the snapshot."""
+        if size is None:
+            size = self.size - off
+        self._check(off, size)
+        view = self._durable[off : off + size].view(np.ndarray)
+        view.flags.writeable = False
+        return view
 
     def fsync(self) -> None:
         """For file-backed regions: push the durable image to stable media."""
@@ -460,8 +502,8 @@ class PMem:
     def memset_zero(self) -> None:
         """Pre-zero the region (Zero logging requires a zeroed file; the
         paper notes DBs do this anyway to force file-system allocation)."""
-        self._logical[:] = 0
         self._durable[:] = 0
+        self._logical = self._fresh_logical()
         self._dirty.clear()
         self._staged.clear()
         self._wc.clear()

@@ -219,6 +219,10 @@ class RestoreReport:
     #: ids, checksums) and fetched (the assembled images, verdicts)
     h2d_bytes: int = 0
     d2h_bytes: int = 0
+    #: bytes copied out of the pool's durable image on the host during
+    #: the open and the adopt (:attr:`PMem.durable_copy_bytes`); the
+    #: pages themselves are read in place
+    pool_copy_bytes: int = 0
 
 
 class CheckpointManager:
@@ -668,7 +672,8 @@ class CheckpointManager:
         cfg = self.cfg
         self._phase_s = report.phase_s
         self._h2d = self._d2h = 0
-        with span("ckpt.restore.open", into=report.phase_s):
+        copied = self._pool_copy_bytes()
+        with span("ckpt.restore.open", into=report.phase_s) as sp:
             if self.pool is None:
                 if path is None:
                     raise ValueError("nothing to restore from")
@@ -694,7 +699,10 @@ class CheckpointManager:
             # opening the page store (that would replay µlogs before the
             # manifests are verified against the untouched image)
             self._layout = self.pool.pages_layout("pages")
-            img = self.pmem.durable_view()
+            # read in place: every page is copied out (assembled) before
+            # the adopt writes to the pool
+            img = self.pmem.durable_inplace()
+            sp.add(pool_copy_bytes=self._pool_copy_bytes() - copied)
         before: PMemStats = self.pmem.stats.snapshot()
         self._restore_read_bytes = 0
         self._restore_pages_spilled = 0
@@ -703,8 +711,12 @@ class CheckpointManager:
             report.entries_tried += 1
             state = self._try_restore_entry(entry, img, verify)
             if state is not None:
-                with span("ckpt.restore.adopt", into=report.phase_s):
+                with span("ckpt.restore.adopt", into=report.phase_s) as sp:
+                    adopt_from = self._pool_copy_bytes()
                     self._adopt(entry, state)
+                    sp.add(pool_copy_bytes=self._pool_copy_bytes()
+                           - adopt_from)
+                report.pool_copy_bytes = self._pool_copy_bytes() - copied
                 report.step = entry["step"]
                 report.pages_total = sum(
                     len(meta["pages"]) for meta in entry["leaves"].values())
@@ -719,6 +731,9 @@ class CheckpointManager:
                 self.last_restore = report
                 return entry["step"], state
         raise RuntimeError("no manifest entry verifies — checkpoint corrupt")
+
+    def _pool_copy_bytes(self) -> int:
+        return 0 if self.pmem is None else self.pmem.durable_copy_bytes
 
     def _try_restore_entry(self, entry: Dict[str, Any], img: np.ndarray,
                            verify: bool) -> Optional[Dict[str, np.ndarray]]:

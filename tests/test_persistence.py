@@ -143,6 +143,63 @@ def test_crash_before_manifest_commit_restores_previous(tmp_path):
         np.testing.assert_array_equal(got[k], s0[k])
 
 
+@pytest.mark.parametrize("mulog_crash", [False, True])
+def test_file_backed_restore_reads_the_pool_in_place(tmp_path, mulog_crash):
+    """A fresh manager restores a file-backed checkpoint byte for byte
+    while copying less than one pool's worth out of the durable image:
+    the logs, slot headers, µlogs and pages are read where they lie. With
+    a valid µlog left by a crash between its write and its apply, the
+    adopt's page-store open still replays it through the in-place read."""
+    import os
+    from repro.core.pageflush import _SLOT_HDR
+    path = str(tmp_path / "s0.pmem")
+    m = CheckpointManager(path, CFG)
+    m.save(0, make_state(0))
+    m.save(1, make_state(1))
+    state = {k: v.copy() for k, v in make_state(1).items()}
+    state["w_embed"][0, 0] += 1.0
+    m.save(2, state)
+    if mulog_crash:
+        # the µLog steps of a delta onto page 0's shadow slot — invalidate,
+        # write, validate — then a crash before the apply
+        pid = m._leaf_pages["w_embed"][0]
+        shadow, pvn = m._shadow[pid], m.store.table[pid][1]
+        lines = [0, 3]
+        data = np.full((len(lines), CFG.geometry.cache_line), 0xA5,
+                       dtype=np.uint8)
+        ml = m.store.mulogs[0]
+        ml.invalidate()
+        ml.write(pvn + 1, lines, data, target_slot=shadow)
+        ml.validate(pid)
+        m.pmem.crash(evict=lambda li: False)
+        assert ml.read_durable() is not None
+    m.pmem.fsync()
+
+    m2 = CheckpointManager(path, CFG)
+    step, got = m2.restore()
+    assert step == 2
+    for k in state:
+        assert got[k].dtype == state[k].dtype
+        assert got[k].tobytes() == state[k].tobytes()
+    r = m2.last_restore
+    assert r.pool_copy_bytes < os.path.getsize(path)
+    if mulog_crash:
+        # the replay reached the shadow slot: its header and lines
+        layout = m2._layout
+        hdr = _SLOT_HDR.unpack_from(m2.pmem.durable_inplace(),
+                                    layout.slot_off(shadow))
+        assert hdr == (pid, pvn + 1)
+        cl = CFG.geometry.cache_line
+        for li in lines:
+            off = layout.slot_data_off(shadow) + li * cl
+            assert (m2.pmem.durable_inplace(off, cl) == 0xA5).all()
+    # and the restored manager saves on
+    m2.save(3, make_state(3))
+    step3, got3 = CheckpointManager(path, CFG).restore()
+    assert step3 == 3
+    np.testing.assert_array_equal(got3["w_out"], make_state(3)["w_out"])
+
+
 def test_fused_and_staged_pipelines_agree_end_to_end(tmp_path):
     """The fused flush_pack scan and the staged chain route every page
     identically (same CoW/µLog/clean split), restore byte-identical

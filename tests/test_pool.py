@@ -275,6 +275,45 @@ def test_log_handle_parity_with_legacy_classes(technique, padded):
     assert h2.recover().entries == payloads + [b"after-crash"]
 
 
+@pytest.mark.parametrize("technique", ["classic", "header", "zero"])
+@pytest.mark.parametrize("case", ["clean", "torn_tail", "empty"])
+def test_file_backed_log_reopen_recovers_entries_tail_and_lsn(
+        tmp_path, technique, case):
+    """Reopening a log from its pool file (recovery reads the durable
+    image in place) finds the same entries, tail and next LSN the writer
+    had: a torn last append — its first line durable, the rest lost —
+    is not recovered, and an empty log reopens empty."""
+    path = str(tmp_path / "log.pmem")
+    pool = Pool.create(path, SIZE)
+    h = pool.log("log", capacity=1 << 15, technique=technique)
+    payloads = [] if case == "empty" else \
+        [bytes([i + 1]) * (5 + 23 * i) for i in range(6)]
+    for p in payloads:
+        h.append(p)
+    tail, next_lsn = h.tail, h.next_lsn
+    if case == "torn_tail":
+        pm = pool.pmem
+        first_line = (h.base + tail) // pm.geometry.cache_line
+        pm.sfence = lambda: None          # the append's fence never runs
+        h.append(b"t" * 200)              # spans several cache lines
+        del pm.sfence
+        pm.crash(evict=lambda li: li == first_line)
+        assert pm.durable_inplace(h.base + tail, 16).any()  # really torn
+    pool.fsync()
+
+    h2 = Pool.open(path).log("log")
+    assert h2.recovered.entries == payloads
+    assert h2.recovered.lsns == list(range(1, len(payloads) + 1))
+    assert (h2.tail, h2.next_lsn) == (tail, next_lsn)
+    assert (h2.recovered.tail, h2.recovered.next_lsn) == (tail, next_lsn)
+    assert h2.pool.pmem.durable_copy_bytes < h2.length
+    h2.append(b"after reopen")
+    h2.pool.fsync()
+    h3 = Pool.open(path).log("log")
+    assert h3.recovered.entries == payloads + [b"after reopen"]
+    assert h3.next_lsn == next_lsn + 1
+
+
 def test_log_handle_reset_starts_new_generation():
     pool = Pool.create(None, SIZE)
     h = pool.log("log", capacity=1 << 14, technique="zero")
