@@ -8,7 +8,9 @@ run the compiled ``flush_pack`` and ``apply_unpack``.
 
 ``train``   closed loop, one step at a time (``Trainer.run(crash_at=s+1)``,
             so ``run`` never drains the flusher), a checkpoint every
-            ``ckpt_every`` steps. The window is made of whole checkpoint
+            ``ckpt_every`` steps: submitted to the flusher, or, with sync
+            saves (no flusher), saved on the loop before the next step
+            runs. The window is made of whole checkpoint
             intervals, at least ``min_ops`` of them: it starts one while
             ``--seconds`` have not passed and closes when the last one
             ends.
@@ -50,9 +52,21 @@ def load_module(path: Path, name: str):
     return mod
 
 
+#: where a configuration's reference module lies, by its ``reference``
+MODELS = BENCH / "models"
+
+
 def reference_model(cfg: Dict):
-    return load_module(BENCH / "models" / f"{cfg['reference']}.py",
-                       f"bench_ref_{cfg['reference']}")
+    """The configuration's reference module, ``models/<reference>.py``.
+    Besides the plain reference (``make_weights``, ``train_readings``) it
+    holds what the harness needs to know of the architecture:
+    ``PROGRAM`` (``{field of the program's ModelConfig: (the file's key,
+    the built model's attribute held to it, or None)}``),
+    ``train_flops_per_token`` and the CPU rehearsal's sizes ``REDUCED``."""
+    name = f"bench_ref_{cfg['reference']}"
+    if name in sys.modules:
+        return sys.modules[name]
+    return load_module(MODELS / f"{cfg['reference']}.py", name)
 
 
 # ------------------------------------------------------------------ spans
@@ -138,8 +152,9 @@ def program_arch(cfg: Dict) -> str:
     """The configuration's model, entered in the program's registry of
     architectures (``repro.configs``: a module with ``CONFIG``) under a
     name of its own, which ``Trainer`` then builds: ``cfg['arch']``'s
-    entry with the widths, depth and vocabulary the file states, and no
-    head padding for a tensor-parallel split the one-chip cell has not."""
+    entry with the fields the file states (its reference module's
+    ``PROGRAM``), and no head padding for a tensor-parallel split the
+    one-chip cell has not."""
     import types
 
     from repro.configs import get_config
@@ -147,14 +162,8 @@ def program_arch(cfg: Dict) -> str:
     name = "bench_" + cfg["name"].replace("-", "_").replace(".", "_")
     mod = types.ModuleType(f"repro.configs.{name}")
     mod.CONFIG = dataclasses.replace(
-        get_config(cfg["arch"]), name=cfg["name"], num_layers=cfg["n_layer"],
-        d_model=cfg["d_model"], ssm_state=cfg["d_state"],
-        ssm_heads=cfg["nheads"], ssm_head_dim=cfg["headdim"],
-        expand=cfg["expand"], conv_kernel=cfg["d_conv"],
-        chunk=cfg["chunk_size"], vocab_size=cfg["vocab_size"],
-        vocab_pad=cfg["pad_vocab_size_multiple"], tp_heads_multiple=1,
-        tie_embeddings=cfg["tie_embeddings"], norm_eps=cfg["rms_norm_eps"],
-        dtype=cfg["dtype"])
+        get_config(cfg["arch"]), name=cfg["name"], tp_heads_multiple=1,
+        **{f: cfg[k] for f, (k, _) in reference_model(cfg).PROGRAM.items()})
     sys.modules[mod.__name__] = mod
     return name
 
@@ -171,26 +180,24 @@ def trainer_config(cfg: Dict, traffic: Dict, out: Path):
         wal_capacity_steps=dep["wal_capacity_steps"])
 
 
-def check_program_matches(trainer, cfg: Dict) -> None:
+def check_program_matches(trainer, cfg: Dict) -> Dict[str, Any]:
     """The configuration file states what runs: refuse a program whose
-    model, page size or flusher differs from it."""
+    model, page size or flusher differs from it. The model's keys, and the
+    attribute of the built model each is read from, are its reference
+    module's ``PROGRAM``. Returns what was compared, by key."""
     m = trainer.cfg
     dep = cfg["deployment"]
-    mult = cfg["pad_vocab_size_multiple"]
+    table = reference_model(cfg).PROGRAM
+    vocab, pad = (cfg[table[f][0]] for f in ("vocab_size", "vocab_pad"))
     fl = trainer.flusher
-    ran = {"d_model": m.d_model, "n_layer": m.num_layers,
-           "d_state": m.ssm_state, "headdim": m.ssm_head_dim,
-           "nheads": m.padded_ssm_heads, "expand": m.expand,
-           "d_conv": m.conv_kernel, "chunk_size": m.chunk,
-           "vocab_size": m.vocab_size, "padded_vocab": m.padded_vocab,
-           "dtype": m.dtype, "rms_norm_eps": m.norm_eps,
-           "tie_embeddings": m.tie_embeddings,
-           "page_size": trainer.manager.cfg.page_size,
-           "manifest_capacity": trainer.manager.cfg.manifest_capacity,
-           "async_flush": fl is not None,
-           "max_pending": None if fl is None else fl._queues[0].maxsize}
-    want = dict({k: cfg[k] for k in ran if k in cfg},
-                padded_vocab=-(-cfg["vocab_size"] // mult) * mult,
+    ran = {k: getattr(m, attr) for k, attr in table.values() if attr}
+    want = {k: cfg[k] for k, attr in table.values() if attr}
+    ran.update(padded_vocab=m.padded_vocab,
+               page_size=trainer.manager.cfg.page_size,
+               manifest_capacity=trainer.manager.cfg.manifest_capacity,
+               async_flush=fl is not None,
+               max_pending=None if fl is None else fl._queues[0].maxsize)
+    want.update(padded_vocab=-(-vocab // pad) * pad,
                 page_size=dep["page_size"],
                 manifest_capacity=dep["manifest_capacity"],
                 async_flush=dep["async_flush"],
@@ -200,6 +207,7 @@ def check_program_matches(trainer, cfg: Dict) -> None:
         raise RuntimeError(f"the program runs another model or deployment "
                            f"than the configuration states (ran, stated): "
                            f"{bad}")
+    return want
 
 
 def set_weights(trainer, weights) -> None:
@@ -539,7 +547,8 @@ class Driver:
             else:
                 train_step(trainer, spans, s)
             s += 1
-        trainer.flusher.wait()
+        if trainer.flusher is not None:
+            trainer.flusher.wait()
         self.phase(f"steps 0-{s - 1} and the first "
                    f"{self.traffic['warm_saves']} saves, drained", t0)
         t0 = time.perf_counter()
@@ -563,7 +572,8 @@ class Driver:
             if sp.name == "save" and lo <= sp.t1 <= hi))
         t0 = time.perf_counter()
         gate.close(timeout=300)
-        trainer.flusher.close()          # skips the saves still queued
+        if trainer.flusher is not None:
+            trainer.flusher.close()      # skips the saves still queued
         self.phase("save in flight at the close finished", t0)
         self.run.memory_peak_bytes = _memory_peak()
         done = [c for c in gate.completed if c[1] is not None]

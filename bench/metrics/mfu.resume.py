@@ -1,6 +1,7 @@
 """A whole resume's share of the chip's bf16 peak, in %: the training
-FLOPs of the one step a resume runs (``work.py``, batch × sequence tokens),
-over ``resume_s`` times the peak. It stands beside
+FLOPs of the one step a resume runs (the reference module's
+``train_flops_per_token``, batch × sequence tokens), over ``resume_s``
+times the peak. It stands beside
 ``apply_unpack_roofline`` over the whole resume, so a kernel taken off the
 restore's path still leaves a share that a claim must move."""
 
@@ -12,5 +13,5 @@ def read(run):
     if seconds is None:
         return None
     flops = run.cfg["batch"] * run.cfg["seq"] \
-        * run.work.mamba2_train_flops_per_token(run.cfg)
+        * run.model.train_flops_per_token(run.cfg)
     return 100.0 * flops / (seconds * run.peaks["bf16_flops_per_s"])

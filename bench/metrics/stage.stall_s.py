@@ -1,13 +1,14 @@
 """Seconds per save that the step loop spends staging the state to the
-host: ``Trainer._ckpt_state`` (device to host) plus
-``AsyncFlusher.stage`` (the host copy), over the window's submits."""
+host: ``Trainer._ckpt_state`` (device to host) plus, with a flusher,
+``AsyncFlusher.stage`` (the host copy), over the window's stagings (one a
+save, async or sync)."""
 
 
 def read(run):
-    subs = run.spans.within("submit", *run.window)
-    if not subs:
-        return None
     lo, hi = run.window
-    total = sum(s.seconds for s in run.spans.within("ckpt_state", lo, hi))
+    stagings = run.spans.within("ckpt_state", lo, hi)
+    if not stagings:
+        return None
+    total = sum(s.seconds for s in stagings)
     total += sum(s.seconds for s in run.spans.within("stage", lo, hi))
-    return total / len(subs)
+    return total / len(stagings)

@@ -30,6 +30,11 @@ bias-corrected moments in float32, decoupled weight decay on every leaf,
 and a learning rate scaled by linear warm-up then cosine decay. A leaf
 kept in bfloat16 is updated in float32 and rounded back to bfloat16, as
 mixed-precision training stores it.
+
+The harness's knowledge of the architecture lives here too, so that it
+names no key of one model: ``PROGRAM`` (the program's model as the file
+states it, and what the built model is held to), ``train_flops_per_token``
+(the yardstick of ``mfu.*``) and ``REDUCED`` (the CPU rehearsal's sizes).
 """
 
 from __future__ import annotations
@@ -48,6 +53,53 @@ def dims(cfg: Dict) -> Dict[str, int]:
     return dict(D=cfg["d_model"], L=cfg["n_layer"], H=H, P=P, N=N, di=di,
                 C=di + 2 * N, E=2 * di + 2 * N + H, K=cfg["d_conv"],
                 Q=cfg["chunk_size"], V=-(-cfg["vocab_size"] // mult) * mult)
+
+
+# ------------------------------------------------ the harness's questions
+
+#: the CPU rehearsal's sizes, the program's ``mamba2_130m.reduced()``
+REDUCED = dict(d_model=64, n_layer=4, d_state=16, nheads=4, headdim=16,
+               vocab_size=512, pad_vocab_size_multiple=16, chunk_size=16)
+
+#: each field of the program's ``ModelConfig`` that the file states: the
+#: file's key that gives it, and the attribute of the built model that is
+#: held to that key (None where the built model has none of its own)
+PROGRAM = {
+    "num_layers": ("n_layer", "num_layers"),
+    "d_model": ("d_model", "d_model"),
+    "ssm_state": ("d_state", "ssm_state"),
+    "ssm_heads": ("nheads", "padded_ssm_heads"),
+    "ssm_head_dim": ("headdim", "ssm_head_dim"),
+    "expand": ("expand", "expand"),
+    "conv_kernel": ("d_conv", "conv_kernel"),
+    "chunk": ("chunk_size", "chunk"),
+    "vocab_size": ("vocab_size", "vocab_size"),
+    "vocab_pad": ("pad_vocab_size_multiple", None),
+    "tie_embeddings": ("tie_embeddings", "tie_embeddings"),
+    "norm_eps": ("rms_norm_eps", "norm_eps"),
+    "dtype": ("dtype", "dtype"),
+}
+
+
+def train_flops_per_token(cfg: Dict) -> float:
+    """FLOPs of one training step per token as the configuration runs it:
+    forward and backward (twice the forward), with nothing recomputed
+    counted.
+
+    Per layer and token, the forward's matrix products are the input
+    projection (2 D E), the output projection (2 di D), and the chunked
+    SSD scan with chunk length Q: C B^T inside the chunk (2 Q N), the
+    decay-weighted sum over the chunk (2 Q H P), the chunk's end state
+    (2 H P N) and the inter-chunk output (2 H P N); the depthwise
+    convolution adds 2 K (di + 2 N). The tied output projection over the
+    padded vocabulary adds 2 D V per token."""
+    d = dims(cfg)
+    D, L, H, P, N, di, E, K, V = (
+        d[k] for k in "D L H P N di E K V".split())
+    Q = min(d["Q"], cfg["seq"])
+    layer = (2 * D * E + 2 * di * D + 2 * Q * N + 2 * Q * H * P
+             + 4 * H * P * N + 2 * K * (di + 2 * N))
+    return 3.0 * (L * layer + 2 * D * V)
 
 
 # ------------------------------------------------------------------ weights

@@ -3,9 +3,10 @@
 A reader is a module with ``read(run)``: it takes the metric from the
 run's spans, counters and trace (``drive.Run``), and returns a number, or
 None where the run holds nothing for it to read; the metric is then left
-out of the result. ``run.peaks`` (the chip's row of ``peaks.json``) and
-:func:`value` (another metric of the same run) are there for readers that
-need them.
+out of the result. ``run.peaks`` (the chip's row of ``peaks.json``),
+``run.work`` (``work.py``), ``run.model`` (the configuration's reference
+module) and :func:`value` (another metric of the same run) are there for
+readers that need them.
 """
 
 from __future__ import annotations
@@ -13,7 +14,7 @@ from __future__ import annotations
 from pathlib import Path
 from typing import Dict, List, Optional
 
-from drive import load_module
+from drive import load_module, reference_model
 
 BENCH = Path(__file__).resolve().parent
 TRACE = load_module(BENCH / "trace.py", "bench_trace")
@@ -38,6 +39,7 @@ def read(run, specs: List[Dict], device_kind: str) -> Dict[str, Dict]:
     its reader finds something to read for."""
     run.peaks = WORK.peaks(device_kind)
     run.work = WORK
+    run.model = reference_model(run.cfg)
     run.trace_lib = TRACE
     out = {}
     for spec in specs:

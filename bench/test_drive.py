@@ -1,5 +1,6 @@
-"""CPU rehearsal of both traffic loops, of the check that decides
-``correct``, and of ``run.py``'s refusals.
+"""CPU rehearsal of both traffic loops (train with async and with sync
+saves), of the check that decides ``correct``, and of ``run.py``'s
+refusals.
 
 Runs the harness as ``run.py`` does, minus the look for a chip, at the
 reduced Mamba-2 on the CPU (the kernels' jnp oracles run in place of the
@@ -26,6 +27,7 @@ import check  # noqa: E402
 import drive  # noqa: E402
 
 TRAIN, RESUME = "m2-130m.async.ckpt4", "m2-130m.sync.resume"
+SYNC_TRAIN = "m2-130m.sync.ckpt8"          # saves on the loop, no flusher
 SEED = 2**31 + 4242        # larger than 32 signed bits hold
 #: more seeds for the fault whose reading depends on the data
 HALF_BATCH_SEEDS = (SEED, 7, 123456789, 2**33 + 5)
@@ -41,8 +43,9 @@ def _run(cell, tmp_path, seconds=2.0, fault=None, seed=SEED):
     return d, run, checks, correct
 
 
-def test_train_loop_counts_whole_intervals(tmp_path):
-    d, run, checks, correct = _run(TRAIN, tmp_path)
+@pytest.mark.parametrize("cell", [TRAIN, SYNC_TRAIN])
+def test_train_loop_counts_whole_intervals(tmp_path, cell):
+    d, run, checks, correct = _run(cell, tmp_path)
     k = d.traffic["ckpt_every"]
     lo, hi = run.window
     steps = run.spans.within("step", lo, hi)
@@ -51,8 +54,16 @@ def test_train_loop_counts_whole_intervals(tmp_path):
     assert run.ops >= k * d.traffic["min_ops"]
     assert steps[-1].t1 <= hi and (steps[-1].step + 1) % k == 0
     assert steps[0].t0 - lo < 0.05               # no gap at the opening
-    # saves ran on the flusher's thread, in the window too
-    assert any(lo <= s.t1 <= hi for s in run.spans.items if s.name == "save")
+    # saves ran in the window too: on the flusher's thread, or with sync
+    # saves each inside the step that ends its interval
+    saves = [s for s in run.spans.items
+             if s.name == "save" and lo <= s.t1 <= hi]
+    assert saves
+    if not d.cfg["deployment"]["async_flush"]:
+        assert len(saves) == run.ops // k
+        for sv in saves:
+            assert any(st.t0 <= sv.t0 and sv.t1 <= st.t1
+                       and st.step + 1 == sv.step for st in steps)
     assert checks["restore_bytes_differing"]["value"] == 0
     assert checks["restored_step_behind"]["value"] == 0
     assert correct, checks
@@ -72,10 +83,11 @@ def test_resume_loop_counts_whole_resumes(tmp_path):
     assert correct, checks
 
 
-def test_traced_train_run_reads_the_host_side_metrics(tmp_path):
+@pytest.mark.parametrize("cell", [TRAIN, SYNC_TRAIN])
+def test_traced_train_run_reads_the_host_side_metrics(tmp_path, cell):
     import readers
 
-    cfg, traffic = reduced_cell(TRAIN)
+    cfg, traffic = reduced_cell(cell)
     d = drive.Driver(cfg, traffic, SEED, 1.0, tmp_path / "run",
                      t_start=time.perf_counter(), trace_dir=tmp_path / "trace")
     run = d.train()
@@ -83,14 +95,20 @@ def test_traced_train_run_reads_the_host_side_metrics(tmp_path):
     run.summary = readers.TRACE.summarize(run.trace)
     assert run.save_dirty_blocks                  # counted for the saves kept
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
-    mine = [m for m in spec["per_layer"] if TRAIN in m["workloads"]]
+    mine = [m for m in spec["per_layer"] if cell in m["workloads"]]
     got = readers.read(run, mine, "TPU v5 lite")
-    # the CPU has no TPU plane, so the trace's readers find nothing there
-    for name in ("train.step_s", "mfu.train", "stage.stall_s",
-                 "flusher.wait_s", "mfu.save"):
-        assert got[name]["value"] > 0, name
+    # every metric listed for the cell reads a number; the CPU has no TPU
+    # plane, so only the trace's readers find nothing there
+    for m in mine:
+        if m["source"] != "device_trace":
+            assert got[m["name"]]["value"] > 0, m["name"]
     assert 0 < got["mfu.save"]["value"] < 100
     assert "flush_pack_roofline" not in got
+    # staging reads every save; the queue's wait reads submits, which sync
+    # saves have none of
+    assert readers.value(run, "stage.stall_s") > 0
+    assert (readers.value(run, "flusher.wait_s") is None) == (
+        cell == SYNC_TRAIN)
 
 
 def test_trainer_takes_the_configured_manifest_capacity(tmp_path):
@@ -109,6 +127,7 @@ def test_trainer_takes_the_configured_manifest_capacity(tmp_path):
 @pytest.mark.parametrize("cell,fault,seed", [
     (TRAIN, "unchanged_state", SEED), (TRAIN, "flip_byte", SEED),
     (RESUME, "unchanged_state", SEED), (RESUME, "flip_byte", SEED),
+    (SYNC_TRAIN, "unchanged_state", SEED), (SYNC_TRAIN, "flip_byte", SEED),
 ] + [(TRAIN, "half_batch", s) for s in HALF_BATCH_SEEDS])
 def test_each_fault_makes_the_run_incorrect(tmp_path, cell, fault, seed):
     _, _, checks, correct = _run(cell, tmp_path, seconds=0.5, fault=fault,
@@ -150,7 +169,9 @@ def test_run_py_refuses_without_the_program(tmp_path):
 def test_benchmark_json_names_files_that_exist():
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     for c in spec["configs"]:
-        assert (ROOT / c["file"]).is_file()
+        model = drive.reference_model(json.loads((ROOT / c["file"]).read_text()))
+        for piece in ("PROGRAM", "train_flops_per_token", "REDUCED"):
+            assert hasattr(model, piece), (c["name"], piece)
     for w in spec["workloads"]:
         assert (HERE / "traffic" / f"{w['traffic']}.json").is_file()
     for m in spec["end_to_end"] + spec["per_layer"]:

@@ -1,4 +1,6 @@
-"""Checks of the work functions and the peaks table (``work.py``).
+"""Checks of the work functions and the peaks table (``work.py``), and of
+the Mamba-2 training FLOPs its reference module gives the ``mfu.*``
+readers.
 
     python -m pytest -q bench/test_work.py
 """
@@ -15,6 +17,7 @@ sys.path.insert(0, str(HERE))
 from drive import load_module  # noqa: E402
 
 W = load_module(HERE / "work.py", "bench_work")
+M = load_module(HERE / "models" / "mamba2.py", "bench_ref_mamba2")
 
 #: the reduced Mamba-2 the CPU tests train: 4 layers, d_model 64, 4 heads
 #: of 16, state 16, chunk 16, vocabulary 512 (already a multiple of 16)
@@ -35,20 +38,21 @@ def test_mamba2_flops_by_hand():
     assert layer == 36_608
     head = 2 * 64 * 512               # tied output projection: 65,536
     forward = 4 * layer + head        # 211,968
-    assert W.mamba2_train_flops_per_token(SMALL) == 3 * forward == 635_904
+    assert M.train_flops_per_token(SMALL) == 3 * forward == 635_904
 
 
 def test_chunk_is_capped_by_the_sequence():
     short = dict(SMALL, seq=8)
-    assert W.mamba2_train_flops_per_token(short) < \
-        W.mamba2_train_flops_per_token(SMALL)
+    assert M.train_flops_per_token(short) < \
+        M.train_flops_per_token(SMALL)
 
 
 def test_published_size_is_in_the_expected_range():
     cfg = json.loads((HERE / "configs" / "mamba2-130m.async.json").read_text())
-    per_token = W.mamba2_train_flops_per_token(cfg)
+    per_token = M.train_flops_per_token(cfg)
     # about 3 x 2 x (params ~ 130 M, embedding included), plus the scan
     assert 0.8e9 < per_token < 1.0e9
+    assert per_token == 891_297_792
 
 
 def test_scan_bytes_of_one_leaf():

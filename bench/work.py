@@ -2,9 +2,10 @@
 
 These are the yardstick's numerators: a roofline share or a utilization
 divides the least time this work could take at the chip's peaks
-(``peaks.json``) by the time it took. They count what the algorithm needs,
-not what a kernel happens to do, so a kernel that does more than it must
-reads lower, never higher.
+(``peaks.json``) by the time it took. They count what the algorithm
+needs, not what a kernel happens to do, so a kernel that does more than it
+must reads lower, never higher. A model's training FLOPs per token are
+its reference module's (``models/<reference>.py``).
 """
 
 from __future__ import annotations
@@ -24,30 +25,6 @@ def peaks(device_kind: str) -> Dict[str, float]:
         raise KeyError(f"no peaks for device kind {device_kind!r} in "
                        f"{PEAKS.name}; known: {sorted(table)}")
     return table[device_kind]
-
-
-def mamba2_train_flops_per_token(cfg: Dict) -> float:
-    """FLOPs of one training step per token of a Mamba-2 model as the
-    configuration runs it: forward and backward (twice the forward), with
-    nothing recomputed counted.
-
-    Per layer and token, the forward's matrix products are the input
-    projection (2 D E), the output projection (2 di D), and the chunked
-    SSD scan with chunk length Q: C B^T inside the chunk (2 Q N), the
-    decay-weighted sum over the chunk (2 Q H P), the chunk's end state
-    (2 H P N) and the inter-chunk output (2 H P N); the depthwise
-    convolution adds 2 K (di + 2 N). The tied output projection over the
-    padded vocabulary adds 2 D V per token."""
-    H, P, N = cfg["nheads"], cfg["headdim"], cfg["d_state"]
-    D, L, K, Q = cfg["d_model"], cfg["n_layer"], cfg["d_conv"], cfg["chunk_size"]
-    Q = min(Q, cfg["seq"])
-    di = H * P
-    E = 2 * di + 2 * N + H
-    mult = cfg["pad_vocab_size_multiple"]
-    V = -(-cfg["vocab_size"] // mult) * mult
-    layer = (2 * D * E + 2 * di * D + 2 * Q * N + 2 * Q * H * P
-             + 4 * H * P * N + 2 * K * (di + 2 * N))
-    return 3.0 * (L * layer + 2 * D * V)
 
 
 def save_scan_bytes(leaf_nbytes: Iterable[int], dirty_blocks: int,
